@@ -44,7 +44,7 @@ def main():
     emit("rotate_1M_jnp", us, f"flops={2*d1*(128+128):.3g};bytes={d1*4*2:.3g}")
     signs = _signs(key, pad_len(d1))
     x1p = x1[None]
-    us = _time(lambda v: fused_rotate(v, signs), x1p, n=1)
+    us = _time(lambda v: fused_rotate(v, signs, interpret=True), x1p, n=1)
     emit("rotate_1M_pallas_interpret", us,
          f"flops={2*d1*(128+128):.3g};bytes={d1*4*2:.3g}")
 

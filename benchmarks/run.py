@@ -23,6 +23,7 @@ from benchmarks import (bench_algorithms, bench_analysis, bench_averaging,
                         bench_quantizer, bench_roofline, bench_swt,
                         bench_time)
 from benchmarks.common import RECORDS
+from repro.utils.cache import enable_compile_cache
 
 BENCHES = [
     ("Fig1_peers", bench_peers.main),
@@ -89,6 +90,7 @@ def _write_merged(path: str, records, quick: bool):
 
 
 def main() -> None:
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     only = _arg_value("--only")
     print("name,us_per_call,derived")

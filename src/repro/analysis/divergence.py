@@ -15,7 +15,7 @@ the distinguished ``"*"`` = unknown provenance, treated as varying over
 everything):
 
 * entering a ``shard_map``, each body input varies over the axes its
-  ``in_names`` shard it along (different devices see different blocks);
+  ``in_specs`` shard it along (different devices see different blocks);
 * ``axis_index(a)`` introduces variance over ``a``; ``psum``/``pmax``/
   ``pmin``/``all_gather`` *remove* the reduced/gathered axes (every device
   ends with the same aggregate); ``psum_scatter`` and ``ppermute`` keep or
@@ -23,7 +23,7 @@ everything):
 * everything else joins its operands (set union) — sound for elementwise
   and structural ops;
 * exiting the ``shard_map``, an output still varying over an axis that its
-  ``out_names`` entry does not carry is reported as a divergence escape.
+  ``out_specs`` entry does not carry is reported as a divergence escape.
 
 :func:`check_divergence` wraps the run and returns the violations.
 """
@@ -54,11 +54,13 @@ def _eqn_axes(eqn) -> Axes:
     return frozenset(str(a) for a in ax)
 
 
-def _names_axes(names_entry) -> Axes:
-    """Mesh axes mentioned by one in_names/out_names dict entry
-    ``{array_dim: (axis, ...)}``."""
+def _spec_axes(spec) -> Axes:
+    """Mesh axes mentioned by one in_specs/out_specs ``PartitionSpec``
+    (entries are None, an axis name, or a tuple of axis names)."""
     out = set()
-    for axes in dict(names_entry).values():
+    for axes in spec:
+        if axes is None:
+            continue
         if isinstance(axes, (str, int)):
             out.add(str(axes))
         else:
@@ -94,16 +96,16 @@ class DivergenceDomain(JoinAllDomain):
         return super().transfer(eqn, ins)
 
     def enter_shard_map(self, eqn, ins: List[Axes]) -> List[Axes]:
-        in_names = eqn.params["in_names"]
-        return [v | _names_axes(spec) for v, spec in zip(ins, in_names)]
+        in_specs = eqn.params["in_specs"]
+        return [v | _spec_axes(spec) for v, spec in zip(ins, in_specs)]
 
     def exit_shard_map(self, eqn, outs: List[Axes],
                        ctx: FlowContext) -> List[Axes]:
-        out_names = eqn.params["out_names"]
+        out_specs = eqn.params["out_specs"]
         mesh_axes = frozenset(str(a) for a in eqn.params["mesh"].axis_names)
         mapped = []
-        for i, (v, spec) in enumerate(zip(outs, out_names)):
-            carried = _names_axes(spec)
+        for i, (v, spec) in enumerate(zip(outs, out_specs)):
+            carried = _spec_axes(spec)
             escaped = (v & (mesh_axes | {_UNKNOWN})) - carried
             if escaped:
                 what = ("unknown-provenance value" if _UNKNOWN in escaped
@@ -112,7 +114,7 @@ class DivergenceDomain(JoinAllDomain):
                 ctx.facts.append(Violation(
                     "spmd-divergence", ctx.where,
                     f"shard_map output {i} commits a {what} through "
-                    f"out_names {dict(spec) or 'P()'} — device 0's copy "
+                    f"out_specs {spec} — device 0's copy "
                     f"is silently published as replicated state"))
             # outside the mesh the committed value is what the spec says
             mapped.append(v - mesh_axes - {_UNKNOWN})

@@ -19,7 +19,7 @@ interpreter that understands the control primitives jax actually emits:
     the branch outputs (branches are alternatives, not sequences).
   - ``shard_map``: delegate entry/exit value mapping to the domain so a
     mesh-aware analysis (e.g. divergence) can seed per-axis facts from
-    ``in_names`` and audit escapes against ``out_names``.
+    ``in_specs`` and audit escapes against ``out_specs``.
 
 Domains subclass :class:`FlowDomain`; analyzers live in ``wire.py``,
 ``intervals.py`` and ``divergence.py``.
@@ -30,7 +30,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any
 
-from jax._src import core as jcore
+from jax._src.core import DropVar  # not exported by jax.extend.core
+from jax.extend import core as jcore
 
 # Primitives whose params hold a single positionally-compatible subjaxpr.
 _CALL_JAXPR_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
@@ -125,7 +126,7 @@ def _read(domain: FlowDomain, env: dict, atom) -> Any:
 
 
 def _write(env: dict, var, val) -> None:
-    if isinstance(var, jcore.DropVar):
+    if isinstance(var, DropVar):
         return
     env[var] = val
 

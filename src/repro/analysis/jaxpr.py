@@ -32,7 +32,7 @@ from collections import Counter, defaultdict
 from typing import Any, Dict, Iterator, List, Tuple
 
 from jax import dtypes
-from jax.core import ClosedJaxpr, Jaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,7 +221,7 @@ def _key_usage(jaxpr: Jaxpr, memo) -> Tuple[List[Tuple[str, List[str]]],
             # fold_in is domain separation: never a violation, and the
             # folded OUTPUT is a fresh derivation path.
             continue
-        if (name == "pjit"
+        if (name == "jit"
                 and str(eqn.params.get("name", "")) in _ATOMIC_SAMPLERS):
             outs = ",".join(str(getattr(v, "aval", "?"))
                             for v in eqn.outvars)

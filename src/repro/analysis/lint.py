@@ -282,7 +282,7 @@ def _trace_exchange(codec_up_spec: str, codec_dn_spec: str,
     from repro.configs.base import FedConfig
     from repro.core.exchange_local import make_shardlocal_exchange
 
-    mesh = AbstractMesh((("data", n), ("model", 2)))
+    mesh = AbstractMesh((n, 2), ("data", "model"))
     fed = FedConfig(n_clients=n, s=n, bits=8, codec_up=codec_up_spec,
                     codec_down=codec_dn_spec)
     up = resolve_codec(None, fed, direction="up")

@@ -15,12 +15,12 @@ code) in transitively.
 
 from __future__ import annotations
 
-from jax import core
+from jax.extend.core import Primitive
 from jax.interpreters import batching, mlir
 
 MARK_PRIM_NAME = "wire_mark"
 
-wire_mark_p = core.Primitive(MARK_PRIM_NAME)
+wire_mark_p = Primitive(MARK_PRIM_NAME)
 wire_mark_p.def_impl(lambda x, **_: x)
 wire_mark_p.def_abstract_eval(lambda x, **_: x)
 mlir.register_lowering(wire_mark_p, lambda ctx, x, **_: [x])

@@ -327,8 +327,8 @@ class GroupedLatticeCodec(CodecBase):
     rotated-space pipeline runs ONE batched exchange with per-message wrap
     moduli (``LatticeWire.levels``), so a round can mix b=8 fast clients
     with b=4 stragglers at no extra rotation passes. Runs on every kernel
-    backend — the Pallas kernels take the moduli as a lane-aligned levels
-    row next to the γ rows. Uplink only (the downlink broadcast is one
+    backend — the Pallas kernels take the moduli as a per-message SMEM
+    operand next to the γ scalars. Uplink only (the downlink broadcast is one
     message).
 
     Wire accounting is the MEMBER codec's: ``wire_width_per_client[i]`` is

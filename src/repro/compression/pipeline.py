@@ -78,7 +78,7 @@ class LatticeWire(NamedTuple):
     optionally carries PER-MESSAGE quantization levels (a (m,) f32 array of
     powers of two <= 2^bits) for heterogeneous per-client bit budgets —
     supported by every backend: the Pallas kernels take the moduli as a
-    lane-aligned levels row riding next to the γ rows.
+    per-message SMEM operand riding next to the γ scalars.
     """
     bits: int
     pack: int = 1
@@ -155,7 +155,7 @@ class Backend(NamedTuple):
     The quantizing ops additionally take ``pack`` (sub-byte packed codes,
     :mod:`repro.kernels.exchange` layout) and ``levels2`` (optional
     per-message quantization levels for heterogeneous bit budgets — on the
-    Pallas backends the moduli ride as a lane-aligned levels row).
+    Pallas backends the moduli ride as a per-message SMEM operand).
     """
     name: str
     rotate: Callable    # (x2, signs, *, block, inverse) -> y2
@@ -185,8 +185,8 @@ def _rotate_jnp(x2, signs, *, block=DEFAULT_BLOCK, inverse=False):
     x = x2.astype(jnp.float32)
     if not inverse:
         x = x * signs[None, :]
-    y = jnp.einsum("ij,bjk,kl->bil", hr, x.reshape(m * nb, r, c),
-                   hc) * (1.0 / np.sqrt(b))
+    y = jnp.einsum("ij,bjk,kl->bil", hr, x.reshape(m * nb, r, c), hc,
+                   precision=jax.lax.Precision.HIGHEST) * (1.0 / np.sqrt(b))
     y = y.reshape(m, d_pad)
     if inverse:
         y = y * signs[None, :]

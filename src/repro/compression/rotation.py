@@ -11,43 +11,12 @@ the sign diagonal, so the inverse is D H_b / sqrt(b).
 """
 from __future__ import annotations
 
-from functools import lru_cache
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-DEFAULT_BLOCK = 16_384  # 128 x 128
-
-
-@lru_cache(maxsize=None)
-def hadamard_matrix(n: int) -> np.ndarray:
-    """Sylvester construction; n must be a power of two."""
-    assert n & (n - 1) == 0, n
-    h = np.array([[1.0]], dtype=np.float32)
-    while h.shape[0] < n:
-        h = np.block([[h, h], [h, -h]])
-    return h
-
-
-def _factor(block: int):
-    k = int(np.log2(block))
-    r = 1 << ((k + 1) // 2)
-    c = 1 << (k // 2)
-    assert r * c == block
-    return r, c
-
-
-def _block_size(d: int, block: int) -> int:
-    b = 1
-    while b < min(d, block):
-        b <<= 1
-    return b
-
-
-def pad_len(d: int, block: int = DEFAULT_BLOCK) -> int:
-    b = _block_size(d, block)
-    return int(np.ceil(d / b)) * b
+from repro.kernels.geometry import (DEFAULT_BLOCK, block_size, factor,
+                                    hadamard_matrix, pad_len)
 
 
 def _signs(key, n):
@@ -63,11 +32,11 @@ def rotate(x: jnp.ndarray, key, block: int = DEFAULT_BLOCK,
     The caller keeps the padded length; ``unpad`` with [:d].
     """
     d = x.shape[0]
-    b = _block_size(d, block)
+    b = block_size(d, block)
     padded = pad_len(d, block)
     x = jnp.pad(x.astype(jnp.float32), (0, padded - d))
     s = _signs(key, padded)
-    r, c = _factor(b)
+    r, c = factor(b)
     hr = jnp.asarray(hadamard_matrix(r))
     hc = jnp.asarray(hadamard_matrix(c))
     scale = 1.0 / np.sqrt(b)
@@ -75,7 +44,9 @@ def rotate(x: jnp.ndarray, key, block: int = DEFAULT_BLOCK,
         x = x * s
     blocks = x.reshape(-1, r, c)
     # (H_r ⊗ H_c) vec(X) == H_r @ X @ H_c^T  (H_c symmetric)
-    y = jnp.einsum("ij,bjk,kl->bil", hr, blocks, hc) * scale
+    # full fp32: at default precision a TPU runs one bfloat16 pass
+    y = jnp.einsum("ij,bjk,kl->bil", hr, blocks, hc,
+                   precision=jax.lax.Precision.HIGHEST) * scale
     y = y.reshape(-1)
     if inverse:
         y = y * s

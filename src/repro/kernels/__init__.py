@@ -6,4 +6,6 @@
 #                      snap+inverse-rotate), batched over messages; the
 #                      production path via repro.compression.pipeline
 #   flash_attention.py — attention tile for the model substrate
-#   ops.py           — public jit'd wrappers (interpret on CPU)
+#   ops.py           — public jit'd wrappers (interpret chosen by caller)
+#   geometry.py      — Hadamard block geometry + fp32 matmul, shared with
+#                      repro.compression.rotation (imports nothing of repro)

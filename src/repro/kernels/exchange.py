@@ -42,23 +42,30 @@ All kernels run over a ``(m, nb)`` grid — ``m`` messages by ``nb`` Hadamard
 blocks — with one (r, c) block per step; the two small Hadamard factors hit
 the MXU directly. Batched operands broadcast along ``m`` through the block
 index maps (no HBM materialization of the broadcast). Per-message scales
-``gamma`` ride as lane-aligned (m, 128) rows so each grid step gets a
-regular (1, 128) VMEM tile — direct loads from unblocked ``pl.ANY`` refs
-do not lower on real TPUs.
+``gamma`` ride whole in SMEM as an (m, 1) column and each grid step reads
+its message's scalar at ``program_id(0)``: a per-message VMEM row would
+need a (1, 128) block over an (m, 128) array, which Mosaic refuses for
+m > 1 (the last two block dims must be multiples of (8, 128) or the full
+array dims).
 
 **Per-message levels** (``GroupedLatticeCodec``): each quantizing kernel
 optionally takes ``levels2`` — per-message wrap moduli (powers of two
-<= ``2^bits``) riding as a second lane-aligned (m, 128) row operand, the
-same layout as the γ rows. The kernel reads the modulus from the row
-instead of the static ``2^bits`` constant, so one batched call mixes
+<= ``2^bits``) riding as a second (m, 1) SMEM operand, the same layout
+as the γ scalars. The kernel reads the modulus from it instead of the
+static ``2^bits`` constant, so one batched call mixes
 heterogeneous client bit budgets. Sub-byte packing stays at the STATIC
 ``bits`` container width: every per-message modulus is <= ``2^bits`` by
 construction, so each code fits the container; honest per-member wire
 bits are the codec's accounting job (`GroupedLatticeCodec.bits_for`),
 not the storage layout's.
 
-On this CPU container everything runs with ``interpret=True``; the
-``pallas`` backend flips that off on a real TPU.
+Codes are computed as float, stored as uint32: the float <-> uint32
+casts go through int32 (Mosaic lowers no direct float <-> uint32 convert;
+codes lie in [0, 2^bits), so the detour changes no value).
+
+Every wrapper takes ``interpret`` without a default: the ``pallas``
+backend compiles for the TPU, the ``pallas_interpret`` backend and the
+tests run the same bodies through the Pallas interpreter.
 """
 from __future__ import annotations
 
@@ -68,25 +75,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compression.rotation import (DEFAULT_BLOCK, _block_size, _factor,
-                                        hadamard_matrix, pad_len)
+from repro.kernels.geometry import (DEFAULT_BLOCK, block_size, factor,
+                                    hadamard_matrix, mm_f32, pad_len)
+
+# a whole (m, 1) per-message scalar operand, resident in SMEM
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-LANE = 128
+def _scalars(vals, m: int) -> jnp.ndarray:
+    """Per-message scalars (γ or levels) as an (m, 1) f32 SMEM operand.
 
-
-def _gamma_rows(gammas, m: int) -> jnp.ndarray:
-    """Per-message scales as lane-aligned (m, LANE) rows (TPU-lowerable)."""
-    g = jnp.asarray(gammas, jnp.float32).reshape(-1, 1)
-    return jnp.broadcast_to(g, (m, LANE))
+    The trailing unit dim keeps the operand legal under ``vmap``: the
+    batched block is then (squeezed, m, 1), whose last two dims equal the
+    array's, as Mosaic requires; a flat (m,) would batch to (sq, m).
+    """
+    return jnp.broadcast_to(jnp.asarray(vals, jnp.float32).reshape(-1, 1),
+                            (m, 1))
 
 
 def block_geometry(d: int, block: int = DEFAULT_BLOCK):
     """(b, d_pad, r, c, nb) for a length-d vector under ``block``-blocking."""
-    b = _block_size(d, block)
+    b = block_size(d, block)
     d_pad = pad_len(d, block)
-    r, c = _factor(b)
+    r, c = factor(b)
     return b, d_pad, r, c, d_pad // b
 
 
@@ -106,10 +119,12 @@ def _check_pack(pack: int, bits: int, r: int):
 
 
 def _pack_block(q, pack: int, bits: int):
-    """(r, c) uint32 codes -> (r//pack, c) uint8, packed along sublanes."""
+    """(r, c) integral float codes -> (r//pack, c) uint8, packed along
+    sublanes. Shifts and sums run in int32: Mosaic reduces no unsigned
+    integers, and a packed byte is < 256."""
     r, c = q.shape
-    qi = q.astype(jnp.uint32).reshape(r // pack, pack, c)
-    shifts = (jnp.arange(pack, dtype=jnp.uint32) * bits)[None, :, None]
+    qi = q.astype(jnp.int32).reshape(r // pack, pack, c)
+    shifts = (jnp.arange(pack, dtype=jnp.int32) * bits)[None, :, None]
     return jnp.sum(qi << shifts, axis=1).astype(jnp.uint8)
 
 
@@ -174,8 +189,8 @@ def _rotate_kernel(x_ref, s_ref, hr_ref, hc_ref, o_ref, *, scale: float,
     x = x_ref[0, 0].astype(jnp.float32)
     if not inverse:
         x = x * s_ref[0]
-    y = jnp.dot(hr_ref[...], x, preferred_element_type=jnp.float32)
-    y = jnp.dot(y, hc_ref[...], preferred_element_type=jnp.float32) * scale
+    y = mm_f32(hr_ref[...], x)
+    y = mm_f32(y, hc_ref[...]) * scale
     if inverse:
         y = y * s_ref[0]
     o_ref[0, 0] = y
@@ -186,40 +201,52 @@ def _bits_of(levels: int) -> int:
 
 
 def _modulus(l_ref, levels: int):
-    """Wrap/snap modulus: the per-message levels row when one rides along
-    (grouped codecs), else the static 2^bits container."""
-    return float(levels) if l_ref is None else l_ref[0, 0]
+    """Wrap/snap modulus: this message's entry of the levels operand when
+    one rides along (grouped codecs), else the static 2^bits container."""
+    return float(levels) if l_ref is None else l_ref[pl.program_id(0), 0]
+
+
+def _to_codes(q):
+    """Integral float codes in [0, 2^bits) -> uint32, through int32."""
+    return q.astype(jnp.int32).astype(jnp.uint32)
+
+
+def _from_codes(c):
+    """uint32 codes -> float32, through int32."""
+    return c.astype(jnp.int32).astype(jnp.float32)
 
 
 def _encode_kernel(x_ref, s_ref, u_ref, hr_ref, hc_ref, g_ref, l_ref, c_ref,
                    y_ref, *, scale: float, levels: int, want_rotated: bool,
                    pack: int = 1):
     x = x_ref[0, 0].astype(jnp.float32) * s_ref[0]
-    y = jnp.dot(hr_ref[...], x, preferred_element_type=jnp.float32)
-    y = jnp.dot(y, hc_ref[...], preferred_element_type=jnp.float32) * scale
-    g = g_ref[0, 0]
+    y = mm_f32(hr_ref[...], x)
+    y = mm_f32(y, hc_ref[...]) * scale
+    g = g_ref[pl.program_id(0), 0]
     q = jnp.floor(y / g + u_ref[0, 0])
-    q = jnp.mod(q, _modulus(l_ref, levels)).astype(jnp.uint32)
-    c_ref[0, 0] = q if pack == 1 else _pack_block(q, pack, _bits_of(levels))
+    q = jnp.mod(q, _modulus(l_ref, levels))
+    c_ref[0, 0] = (_to_codes(q) if pack == 1
+                   else _pack_block(q, pack, _bits_of(levels)))
     if want_rotated:
         y_ref[0, 0] = y
 
 
 def _quantize_kernel(y_ref, u_ref, g_ref, l_ref, c_ref, *, levels: int,
                      pack: int = 1):
-    g = g_ref[0, 0]
+    g = g_ref[pl.program_id(0), 0]
     q = jnp.floor(y_ref[0, 0].astype(jnp.float32) / g + u_ref[0, 0])
-    q = jnp.mod(q, _modulus(l_ref, levels)).astype(jnp.uint32)
-    c_ref[0, 0] = q if pack == 1 else _pack_block(q, pack, _bits_of(levels))
+    q = jnp.mod(q, _modulus(l_ref, levels))
+    c_ref[0, 0] = (_to_codes(q) if pack == 1
+                   else _pack_block(q, pack, _bits_of(levels)))
 
 
 def _snap_kernel(c_ref, w_ref, g_ref, l_ref, o_ref, *, levels: int,
                  pack: int = 1):
-    g = g_ref[0, 0]
+    g = g_ref[pl.program_id(0), 0]
     c = c_ref[0, 0]
     if pack > 1:
         c = _unpack_block(c, pack, _bits_of(levels))
-    c = c.astype(jnp.float32)
+    c = _from_codes(c)
     lv = _modulus(l_ref, levels)
     q = c + lv * jnp.round((w_ref[0, 0] / g - c) / lv)
     o_ref[0, 0] = q * g
@@ -229,17 +256,17 @@ def _decode_kernel(c_ref, ref_ref, s_ref, hr_ref, hc_ref, g_ref, l_ref,
                    o_ref, *, scale: float, levels: int, pack: int = 1):
     s = s_ref[0]
     w = ref_ref[0, 0].astype(jnp.float32) * s
-    w = jnp.dot(hr_ref[...], w, preferred_element_type=jnp.float32)
-    w = jnp.dot(w, hc_ref[...], preferred_element_type=jnp.float32) * scale
-    g = g_ref[0, 0]
+    w = mm_f32(hr_ref[...], w)
+    w = mm_f32(w, hc_ref[...]) * scale
+    g = g_ref[pl.program_id(0), 0]
     c = c_ref[0, 0]
     if pack > 1:
         c = _unpack_block(c, pack, _bits_of(levels))
-    c = c.astype(jnp.float32)
+    c = _from_codes(c)
     lv = _modulus(l_ref, levels)
     q = c + lv * jnp.round((w / g - c) / lv)
-    x = jnp.dot(hr_ref[...], q * g, preferred_element_type=jnp.float32)
-    x = jnp.dot(x, hc_ref[...], preferred_element_type=jnp.float32) * scale
+    x = mm_f32(hr_ref[...], q * g)
+    x = mm_f32(x, hc_ref[...]) * scale
     o_ref[0, 0] = x * s
 
 
@@ -248,17 +275,16 @@ def _decode_kernel(c_ref, ref_ref, s_ref, hr_ref, hc_ref, g_ref, l_ref,
 # ---------------------------------------------------------------------------
 
 def _levels_operand(levels2, m: int):
-    """(specs, operands) for an optional per-message levels row — the same
-    lane-aligned (m, LANE) layout the γ rows use."""
+    """(specs, operands) for an optional per-message levels operand — the
+    same (m, 1) SMEM layout the γ scalars use."""
     if levels2 is None:
         return [], []
-    return ([pl.BlockSpec((1, LANE), lambda i, j: (i, 0))],
-            [_gamma_rows(levels2, m)])
+    return [_SMEM_SPEC], [_scalars(levels2, m)]
 
 @partial(jax.jit, static_argnames=("block", "inverse", "interpret"))
 def fused_rotate(x2: jnp.ndarray, signs: jnp.ndarray, *,
                  block: int = DEFAULT_BLOCK, inverse: bool = False,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool) -> jnp.ndarray:
     """Batched randomized-Hadamard rotation: (m, d_pad) -> (m, d_pad)."""
     m, d_pad = x2.shape
     b, _, r, c, nb = block_geometry(d_pad, block)
@@ -284,7 +310,7 @@ def fused_rotate(x2: jnp.ndarray, signs: jnp.ndarray, *,
 def fused_encode(x2: jnp.ndarray, signs: jnp.ndarray, u2: jnp.ndarray,
                  gammas: jnp.ndarray, *, bits: int = 8,
                  block: int = DEFAULT_BLOCK, want_rotated: bool = False,
-                 interpret: bool = True, pack: int = 1, levels2=None):
+                 interpret: bool, pack: int = 1, levels2=None):
     """Rotate + stochastic-round + wrap in one pass.
 
     x2: (m, d_pad) padded messages; u2: U(0,1) rounding noise, same shape;
@@ -328,13 +354,13 @@ def fused_encode(x2: jnp.ndarray, signs: jnp.ndarray, u2: jnp.ndarray,
             pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((r, r), lambda i, j: (0, 0)),
             pl.BlockSpec((c, c), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, LANE), lambda i, j: (i, 0)),
+            _SMEM_SPEC,
         ] + l_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
     )(_blk(x2.astype(jnp.float32), nb, r, c), signs.reshape(nb, r, c),
-      _blk(u2.astype(jnp.float32), nb, r, c), hr, hc, _gamma_rows(gammas, m),
+      _blk(u2.astype(jnp.float32), nb, r, c), hr, hc, _scalars(gammas, m),
       *l_ops)
     codes = res[0].reshape(m, d_pad // pack)
     if want_rotated:
@@ -345,7 +371,7 @@ def fused_encode(x2: jnp.ndarray, signs: jnp.ndarray, u2: jnp.ndarray,
 @partial(jax.jit, static_argnames=("bits", "block", "interpret", "pack"))
 def quantize_codes(y2: jnp.ndarray, u2: jnp.ndarray, gammas: jnp.ndarray, *,
                    bits: int = 8, block: int = DEFAULT_BLOCK,
-                   interpret: bool = True, pack: int = 1,
+                   interpret: bool, pack: int = 1,
                    levels2=None) -> jnp.ndarray:
     """Stochastic-round + wrap of already-rotated coordinates.
 
@@ -374,13 +400,13 @@ def quantize_codes(y2: jnp.ndarray, u2: jnp.ndarray, gammas: jnp.ndarray, *,
         in_specs=[
             pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, LANE), lambda i, j: (i, 0)),
+            _SMEM_SPEC,
         ] + l_specs,
         out_specs=pl.BlockSpec((1, 1, rp, c), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, nb, rp, c), code_dt),
         interpret=interpret,
     )(_blk(y2.astype(jnp.float32), nb, r, c),
-      _blk(u2.astype(jnp.float32), nb, r, c), _gamma_rows(gammas, m),
+      _blk(u2.astype(jnp.float32), nb, r, c), _scalars(gammas, m),
       *l_ops)
     return out.reshape(m, d_pad // pack)
 
@@ -388,7 +414,7 @@ def quantize_codes(y2: jnp.ndarray, u2: jnp.ndarray, gammas: jnp.ndarray, *,
 @partial(jax.jit, static_argnames=("bits", "block", "interpret", "pack"))
 def snap_codes(codes2: jnp.ndarray, wrot2: jnp.ndarray, gammas: jnp.ndarray,
                *, bits: int = 8, block: int = DEFAULT_BLOCK,
-               interpret: bool = True, pack: int = 1,
+               interpret: bool, pack: int = 1,
                levels2=None) -> jnp.ndarray:
     """Positional snap in rotated space: gamma * (c + L round((w/g-c)/L)).
 
@@ -420,13 +446,13 @@ def snap_codes(codes2: jnp.ndarray, wrot2: jnp.ndarray, gammas: jnp.ndarray,
         in_specs=[
             _row_spec(mc, rp, c),
             _row_spec(mw, r, c),
-            pl.BlockSpec((1, LANE), lambda i, j: (i, 0)),
+            _SMEM_SPEC,
         ] + l_specs,
         out_specs=pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, nb, r, c), jnp.float32),
         interpret=interpret,
     )(_blk(codes2.astype(code_dt), nb, rp, c),
-      _blk(wrot2.astype(jnp.float32), nb, r, c), _gamma_rows(gammas, m),
+      _blk(wrot2.astype(jnp.float32), nb, r, c), _scalars(gammas, m),
       *l_ops)
     return out.reshape(m, d_pad)
 
@@ -435,7 +461,7 @@ def snap_codes(codes2: jnp.ndarray, wrot2: jnp.ndarray, gammas: jnp.ndarray,
 def fused_decode(codes2: jnp.ndarray, ref2: jnp.ndarray, signs: jnp.ndarray,
                  gammas: jnp.ndarray, *, bits: int = 8,
                  block: int = DEFAULT_BLOCK,
-                 interpret: bool = True, pack: int = 1,
+                 interpret: bool, pack: int = 1,
                  levels2=None) -> jnp.ndarray:
     """Full positional decode: rotate ref + snap + inverse rotate, fused.
 
@@ -470,12 +496,12 @@ def fused_decode(codes2: jnp.ndarray, ref2: jnp.ndarray, signs: jnp.ndarray,
             pl.BlockSpec((1, r, c), lambda i, j: (j, 0, 0)),
             pl.BlockSpec((r, r), lambda i, j: (0, 0)),
             pl.BlockSpec((c, c), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, LANE), lambda i, j: (i, 0)),
+            _SMEM_SPEC,
         ] + l_specs,
         out_specs=pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, nb, r, c), jnp.float32),
         interpret=interpret,
     )(_blk(codes2.astype(code_dt), nb, rp, c),
       _blk(ref2.astype(jnp.float32), nb, r, c), signs.reshape(nb, r, c),
-      hr, hc, _gamma_rows(gammas, m), *l_ops)
+      hr, hc, _scalars(gammas, m), *l_ops)
     return out.reshape(m, d_pad)

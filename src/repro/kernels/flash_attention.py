@@ -73,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                           "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool):
     """q: (b, tq, h, dh); k, v: (b, tk, kv, dh) with h % kv == 0."""
     b, tq, h, dh = q.shape
     tk, kvh = k.shape[1], k.shape[2]

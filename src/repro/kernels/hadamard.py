@@ -17,18 +17,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.compression.rotation import hadamard_matrix
+from repro.kernels.geometry import hadamard_matrix, mm_f32
 
 
 def _hadamard_kernel(x_ref, hr_ref, hc_ref, o_ref, *, scale: float):
     x = x_ref[0].astype(jnp.float32)
-    y = jnp.dot(hr_ref[...], x, preferred_element_type=jnp.float32)
-    y = jnp.dot(y, hc_ref[...], preferred_element_type=jnp.float32)
+    y = mm_f32(hr_ref[...], x)
+    y = mm_f32(y, hc_ref[...])
     o_ref[0] = y * scale
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def hadamard_blocks(x_blocks: jnp.ndarray, *, interpret: bool = True):
+def hadamard_blocks(x_blocks: jnp.ndarray, *, interpret: bool):
     """x_blocks: (n, r, c) fp32 -> (H_r X H_c)/sqrt(rc), blockwise.
 
     H is symmetric, so this is its own inverse-rotation core. Grid over

@@ -15,6 +15,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 SUB = 8
@@ -24,12 +25,13 @@ TILE = LANE * SUB * 8  # elements per grid step
 def _encode_kernel(y_ref, u_ref, g_ref, o_ref, *, levels: int):
     g = g_ref[0]
     q = jnp.floor(y_ref[...] / g + u_ref[...])
-    o_ref[...] = jnp.mod(q, float(levels)).astype(jnp.uint32)
+    # float -> uint32 through int32: Mosaic has no direct convert
+    o_ref[...] = jnp.mod(q, float(levels)).astype(jnp.int32).astype(jnp.uint32)
 
 
 def _decode_kernel(c_ref, w_ref, g_ref, o_ref, *, levels: int):
     g = g_ref[0]
-    c = c_ref[...].astype(jnp.float32)
+    c = c_ref[...].astype(jnp.int32).astype(jnp.float32)
     q = c + levels * jnp.round((w_ref[...] / g - c) / levels)
     o_ref[...] = q * g
 
@@ -45,7 +47,7 @@ def _tiles(d: int):
 
 @partial(jax.jit, static_argnames=("bits", "interpret"))
 def lattice_encode(y: jnp.ndarray, u: jnp.ndarray, gamma, *, bits: int = 8,
-                   interpret: bool = True):
+                   interpret: bool):
     """y: rotated coords (d,), d % 1024 == 0; u: U(0,1) noise (d,)."""
     d = y.shape[0]
     rows, br = _tiles(d)
@@ -57,7 +59,7 @@ def lattice_encode(y: jnp.ndarray, u: jnp.ndarray, gamma, *, bits: int = 8,
         grid=(rows // br,),
         in_specs=[pl.BlockSpec((br, LANE), lambda i: (i, 0)),
                   pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.uint32),
         interpret=interpret,
@@ -67,7 +69,7 @@ def lattice_encode(y: jnp.ndarray, u: jnp.ndarray, gamma, *, bits: int = 8,
 
 @partial(jax.jit, static_argnames=("bits", "interpret"))
 def lattice_decode(codes: jnp.ndarray, w: jnp.ndarray, gamma, *,
-                   bits: int = 8, interpret: bool = True):
+                   bits: int = 8, interpret: bool):
     """codes: (d,) uint; w: rotated reference (d,)."""
     d = codes.shape[0]
     rows, br = _tiles(d)
@@ -79,7 +81,7 @@ def lattice_decode(codes: jnp.ndarray, w: jnp.ndarray, gamma, *,
         grid=(rows // br,),
         in_specs=[pl.BlockSpec((br, LANE), lambda i: (i, 0)),
                   pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
         interpret=interpret,

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compression.rotation import hadamard_matrix
+from repro.kernels.geometry import hadamard_matrix
 
 
 def hadamard_ref(x_blocks: jnp.ndarray) -> jnp.ndarray:

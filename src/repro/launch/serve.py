@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs import get_config, get_reduced
 from repro.models.model import init_lm
 from repro.serving import Request, ServeEngine
+from repro.utils.cache import enable_compile_cache
 
 
 def main():
@@ -39,6 +40,7 @@ def main():
                          "(quafl|fedavg|fedbuff|sequential|...)")
     ap.add_argument("--algo-rounds", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
     if cfg.encdec:

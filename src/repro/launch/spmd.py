@@ -115,19 +115,28 @@ class SpmdAlgorithm:
 
     # ------------------------------------------------------------------
     def init(self, params0) -> SpmdState:
-        # fresh buffers, NOT views of params0: the eager round donates its
-        # input state, so the state must never alias the caller's params
-        server = {k: jnp.array(v) for k, v in params0.items()}
-        clients = {k: jnp.broadcast_to(v[None], (self.n_slots,) + v.shape)
-                   for k, v in params0.items()}
-        train = TrainState(server=server, clients=clients,
-                           t=jnp.zeros((), jnp.int32))
-        # place the state with the build shardings so GSPMD lays clients
-        # out along the mesh data axis (on the (1,1) CI mesh this is a
-        # no-op; on a pod it is what distributes the replicas)
-        train = jax.device_put(train, self._state_sh)
-        return SpmdState(train=train, sim_time=jnp.zeros(()),
-                         bits_up=jnp.zeros(()), bits_down=jnp.zeros(()))
+        """The state, built by one program straight into the build
+        shardings: GSPMD lays the clients out along the mesh data axis and
+        each device materializes only its own slice (an eager broadcast
+        would first hold all n_slots copies on one device). The outputs
+        are fresh buffers, never views of ``params0`` (the eager round
+        donates its input state). The counters are placed too: the round
+        returns them mesh-replicated, and an unplaced first input would
+        make the second round compile again."""
+        def build(params):
+            train = TrainState(
+                server={k: jnp.array(v) for k, v in params.items()},
+                clients={k: jnp.broadcast_to(v[None],
+                                             (self.n_slots,) + v.shape)
+                         for k, v in params.items()},
+                t=jnp.zeros((), jnp.int32))
+            return SpmdState(train=train, sim_time=jnp.zeros(()),
+                             bits_up=jnp.zeros(()), bits_down=jnp.zeros(()))
+
+        repl = self._state_sh.t
+        shardings = SpmdState(train=self._state_sh, sim_time=repl,
+                              bits_up=repl, bits_down=repl)
+        return jax.jit(build, out_shardings=shardings)(params0)
 
     def device_round(self, state: SpmdState, data, key):
         """One mesh round: sample each slot's (K, b) microbatches from its

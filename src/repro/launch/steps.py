@@ -223,9 +223,17 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
             ex = make_shardlocal_exchange(
                 quant_up, quant_down, mesh, srv_ps, cl_ps, client_axis,
                 n_slots, transport=transport_for_mode(transport))
+            ex_key = jax.random.key_data(jax.random.fold_in(k_q, 3))
+            # compile the local training apart from the exchange. Without
+            # the barrier XLA fuses the client models Ys into whatever the
+            # exchange does with them, so the kernel backend changed the
+            # client models themselves: on four v5e chips the pallas and
+            # jnp rounds' Ys differed by up to 1.8e-5 in the embedding and
+            # their servers by up to 4.9e-5, while the exchange alone, fed
+            # the same Ys, agreed to 0.0
+            Ys = jax.lax.optimization_barrier(Ys)
             server_new, clients_new, qerr = ex(
-                state.server, state.clients, Ys,
-                jax.random.key_data(jax.random.fold_in(k_q, 3)))
+                state.server, state.clients, Ys, ex_key)
             new_state = TrainState(server=server_new, clients=clients_new,
                                    t=state.t + 1)
             return new_state, {

@@ -46,6 +46,7 @@ from repro.configs import get_config, get_reduced
 from repro.configs.base import FedConfig
 from repro.data.synthetic import federated_token_task, lm_token_stream
 from repro.models.model import lm_loss
+from repro.utils.cache import enable_compile_cache
 
 
 def run_registry(args, cfg, fed, key):
@@ -181,6 +182,7 @@ def main():
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     args.scan_chunk = (args.scan_chunk if args.scan_chunk == "auto"

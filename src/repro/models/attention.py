@@ -24,11 +24,11 @@ NEG_INF = -1e30
 # bytes. Softmax still runs in fp32 after the (masked) upcast.
 BF16_SCORE_PARTIALS = False
 
-# Use the Pallas flash-attention kernel for prefill (full/sliding causal
+# Prefill through the Pallas flash-attention kernel (full/sliding causal
 # layers; chunked-local and non-tile-aligned shapes fall back to the jnp
-# path). interpret=True on CPU; set False on real TPUs.
-USE_FLASH_KERNEL = False
-FLASH_INTERPRET = True
+# path): None keeps the jnp path, "pallas" compiles the kernel for the TPU,
+# "pallas_interpret" runs it through the Pallas interpreter (tests).
+FLASH_KERNEL = None
 
 
 def _score_dtype(q):
@@ -99,13 +99,14 @@ def attention_prefill(cfg, spec, q, k, v):
     scale = 1.0 / np.sqrt(dh)
     window = spec.window
 
-    if (USE_FLASH_KERNEL and spec.attn != ATTN_CHUNKED
+    if (FLASH_KERNEL and spec.attn != ATTN_CHUNKED
             and t % 128 == 0 and dh % 8 == 0):
         from repro.kernels.flash_attention import flash_attention
         return flash_attention(
             q, k, v, causal=True,
             window=window if spec.attn == ATTN_SLIDING else 0,
-            softcap=cfg.attn_softcap, interpret=FLASH_INTERPRET)
+            softcap=cfg.attn_softcap,
+            interpret=FLASH_KERNEL == "pallas_interpret")
 
     if spec.attn == ATTN_CHUNKED and window and t % window == 0 and t > window:
         # block-diagonal: reshape into (chunks, window) and attend per chunk

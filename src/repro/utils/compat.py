@@ -1,38 +1,22 @@
-"""JAX version compatibility shims.
+"""Mesh and shard_map construction in one place.
 
-The repo targets the current jax API (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types``); the pinned container ships an older jax where those
-spellings live under ``jax.experimental`` or lack keywords. Every call site
-goes through this module so the rest of the codebase reads like modern jax.
+Every mesh is built with Auto axis types and every ``shard_map`` runs with
+the replication checker off, so call sites stay one-liners.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - depends on container jax
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if _AxisType is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(_AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` (new) or ``jax.experimental.shard_map`` (old).
-
-    ``check_vma`` maps onto the old API's ``check_rep``; both default off —
-    the exchange/MoE bodies use collectives whose replication the checker
-    cannot prove.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map``; ``check_vma`` defaults off — the exchange/MoE
+    bodies use collectives whose replication the checker cannot prove."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

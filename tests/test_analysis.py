@@ -156,7 +156,7 @@ def test_mutation_host_callback_detected():
 
 
 def test_mutation_f64_leak_detected():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def bad(x):
             return x.astype(jnp.float64) * 2.0
 
@@ -244,7 +244,7 @@ def test_rs_transport_audit_clean_and_byte_gate_trips():
 
     # regression fixture: fp32 psum transport under the same budget
     n, d = 4, 1 << 16
-    mesh = AbstractMesh((("data", n), ("model", 2)))
+    mesh = AbstractMesh((n, 2), ("data", "model"))
     fed = FedConfig(n_clients=n, s=n, bits=8,
                     codec_up="lattice_packed:bits=4",
                     codec_down="lattice_packed:bits=4")
@@ -507,7 +507,7 @@ def test_mutation_divergent_escape_detected():
     from repro.analysis.divergence import check_divergence
     from repro.utils.compat import shard_map
 
-    mesh = AbstractMesh((("data", 4),))
+    mesh = AbstractMesh((4,), ("data",))
 
     def body(x):
         return x + jax.lax.axis_index("data").astype(jnp.float32)

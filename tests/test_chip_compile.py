@@ -1,0 +1,186 @@
+"""The main path's Pallas kernels compile for a TPU v5e, at real widths.
+
+Interpret-mode tests cannot see what the chip's compiler refuses (block
+tiling, memory spaces, casts Mosaic does not lower). These tests compile
+each kernel for a v5e that is described, not attached: the TPU compiler
+runs on the host and no chip is needed. Every compiled program must hold
+the kernel (``tpu_custom_call``); nothing is executed.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library at a time, and every pytest worker
+imports every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.exchange import (fused_decode, fused_encode, fused_rotate,
+                                    quantize_codes, snap_codes)
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.hadamard import hadamard_blocks
+from repro.kernels.lattice_quant import lattice_decode, lattice_encode
+
+D = 1 << 20             # 64 Hadamard blocks of 128 x 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2, with the persistent compile cache
+    off (an entry written without a chip cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        cc.reset_cache()
+        if log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        else:
+            os.environ["TPU_LOG_DIR"] = log_dir
+
+
+def _compile(fn, *shapes):
+    """Compile ``fn`` for the shapes; returns the compiled HLO text."""
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _f32(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def _codes(sharding, m, pack):
+    dt = jnp.uint8 if pack > 1 else jnp.uint32
+    return jax.ShapeDtypeStruct((m, D // pack), dt, sharding=sharding)
+
+
+# (m, pack, levels row): b=8 unpacked and b=4 packed two per byte, one or
+# four messages per call, and one grouped-codec call with per-message moduli
+CASES = [(1, 1, False), (1, 2, False), (4, 1, False), (4, 2, False),
+         (4, 2, True)]
+CASE_IDS = [f"m{m}-pack{p}" + ("-levels" if lv else "")
+            for m, p, lv in CASES]
+
+
+def _wire(m, pack, levels, sh):
+    bits = 8 // pack
+    extra = [_f32(sh, m)] if levels else []
+    return bits, extra
+
+
+@pytest.mark.parametrize("m,pack,levels", CASES, ids=CASE_IDS)
+def test_fused_encode_compiles(one_chip, m, pack, levels):
+    bits, extra = _wire(m, pack, levels, one_chip)
+
+    def f(x, s, u, g, *lv):
+        return fused_encode(x, s, u, g, bits=bits, pack=pack,
+                            want_rotated=True, interpret=False,
+                            levels2=lv[0] if lv else None)
+
+    text = _compile(f, _f32(one_chip, m, D), _f32(one_chip, D),
+                    _f32(one_chip, m, D), _f32(one_chip, m), *extra)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m,pack,levels", CASES, ids=CASE_IDS)
+def test_quantize_codes_compiles(one_chip, m, pack, levels):
+    bits, extra = _wire(m, pack, levels, one_chip)
+
+    def f(y, u, g, *lv):
+        return quantize_codes(y, u, g, bits=bits, pack=pack, interpret=False,
+                              levels2=lv[0] if lv else None)
+
+    text = _compile(f, _f32(one_chip, m, D), _f32(one_chip, m, D),
+                    _f32(one_chip, m), *extra)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m,pack,levels", CASES, ids=CASE_IDS)
+def test_snap_codes_compiles(one_chip, m, pack, levels):
+    bits, extra = _wire(m, pack, levels, one_chip)
+
+    def f(c, w, g, *lv):
+        return snap_codes(c, w, g, bits=bits, pack=pack, interpret=False,
+                          levels2=lv[0] if lv else None)
+
+    # one shared rotated reference broadcast over the m messages
+    text = _compile(f, _codes(one_chip, m, pack), _f32(one_chip, 1, D),
+                    _f32(one_chip, m), *extra)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m,pack,levels", CASES, ids=CASE_IDS)
+def test_fused_decode_compiles(one_chip, m, pack, levels):
+    bits, extra = _wire(m, pack, levels, one_chip)
+
+    def f(c, r, s, g, *lv):
+        return fused_decode(c, r, s, g, bits=bits, pack=pack, interpret=False,
+                            levels2=lv[0] if lv else None)
+
+    text = _compile(f, _codes(one_chip, m, pack), _f32(one_chip, 1, D),
+                    _f32(one_chip, D), _f32(one_chip, m), *extra)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m", [1, 4])
+@pytest.mark.parametrize("inverse", [False, True])
+def test_fused_rotate_compiles(one_chip, m, inverse):
+    text = _compile(lambda x, s: fused_rotate(x, s, inverse=inverse,
+                                              interpret=False),
+                    _f32(one_chip, m, D), _f32(one_chip, D))
+    assert "tpu_custom_call" in text
+
+
+def test_vmapped_leaf_exchange_compiles(one_chip):
+    """The mesh step encodes and decodes each leaf per client slot under
+    ``vmap``, which adds a grid axis and a batch dim to every operand."""
+    def f(x, s, u, g, r):
+        codes = jax.vmap(lambda xi, ui, gi: fused_encode(
+            xi, s, ui, gi, bits=8, interpret=False))(x, u, g)
+        return jax.vmap(lambda ci, gi: fused_decode(
+            ci, r, s, gi, bits=8, interpret=False))(codes, g)
+
+    text = _compile(f, _f32(one_chip, 3, 1, D), _f32(one_chip, D),
+                    _f32(one_chip, 3, 1, D), _f32(one_chip, 3, 1),
+                    _f32(one_chip, 1, D))
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_flash_attention_compiles_at_llama_heads(one_chip):
+    """llama3.2-1b attention: 32 query heads, 8 KV heads of 64."""
+    q = jax.ShapeDtypeStruct((1, 1024, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 1024, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    text = _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                    interpret=False),
+                    q, kv, kv)
+    assert "tpu_custom_call" in text
+
+
+def test_hadamard_and_lattice_quant_compile(one_chip):
+    text = _compile(lambda x: hadamard_blocks(x, interpret=False),
+                    _f32(one_chip, 64, 128, 128))
+    assert "tpu_custom_call" in text
+
+    def enc_dec(y, u, w):
+        codes = lattice_encode(y, u, 0.02, bits=8, interpret=False)
+        return lattice_decode(codes, w, 0.02, bits=8, interpret=False)
+
+    text = _compile(enc_dec, _f32(one_chip, D), _f32(one_chip, D),
+                    _f32(one_chip, D))
+    assert text.count("tpu_custom_call") >= 2

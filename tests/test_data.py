@@ -2,10 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # seed container has no hypothesis wheel
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data import (gaussian_mixture, lm_token_stream,
                         make_federated_classification, partition_by_class,

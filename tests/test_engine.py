@@ -35,10 +35,7 @@ from repro.fed.engine import RoundEngine, fedbuff_completion_table
 from repro.models.mlp import init_mlp_classifier, mlp_loss
 from repro.utils.tree import tree_flatten_vector
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # seed container has no hypothesis wheel
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 def _setup(fed, seed=0, iid=True, d=16, hidden=32, classes=4):

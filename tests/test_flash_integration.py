@@ -1,5 +1,6 @@
 """The Pallas flash-attention kernel as a drop-in for the model's prefill
-path: full model forward with USE_FLASH_KERNEL must match the jnp path."""
+path: full model forward with the kernel (interpreted) must match the jnp
+path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,11 +21,11 @@ def test_forward_with_flash_kernel_matches(arch):
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0,
                               cfg.vocab_size)
     ref, _, _ = forward(cfg, params, {"tokens": toks})
-    A.USE_FLASH_KERNEL = True
+    A.FLASH_KERNEL = "pallas_interpret"
     try:
         out, _, _ = forward(cfg, params, {"tokens": toks})
     finally:
-        A.USE_FLASH_KERNEL = False
+        A.FLASH_KERNEL = None
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3,
                                rtol=2e-2)
 
@@ -36,10 +37,10 @@ def test_flash_fallback_on_chunked():
     toks = jax.random.randint(jax.random.PRNGKey(1), (1, 128), 0,
                               cfg.vocab_size)
     ref, _, _ = forward(cfg, params, {"tokens": toks})
-    A.USE_FLASH_KERNEL = True
+    A.FLASH_KERNEL = "pallas_interpret"
     try:
         out, _, _ = forward(cfg, params, {"tokens": toks})
     finally:
-        A.USE_FLASH_KERNEL = False
+        A.FLASH_KERNEL = None
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3,
                                rtol=2e-2)

@@ -18,7 +18,7 @@ from repro.compression.rotation import _signs, pad_len, rotate
                                    (4, 64, 64), (2, 128, 64), (7, 16, 16)])
 def test_hadamard_kernel_shapes(n, r, c):
     x = jax.random.normal(jax.random.PRNGKey(0), (n, r, c))
-    out = hadamard_blocks(x)
+    out = hadamard_blocks(x, interpret=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.hadamard_ref(x)), atol=1e-4)
 
@@ -26,7 +26,7 @@ def test_hadamard_kernel_shapes(n, r, c):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_hadamard_kernel_dtypes(dtype):
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 128)).astype(dtype)
-    out = hadamard_blocks(x)
+    out = hadamard_blocks(x, interpret=True)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref.hadamard_ref(x.astype(jnp.float32))),
         atol=1e-1 if dtype == jnp.bfloat16 else 1e-4)
@@ -35,12 +35,12 @@ def test_hadamard_kernel_dtypes(dtype):
 def test_rotate_pallas_matches_jnp_rotation():
     key = jax.random.PRNGKey(2)
     x = jax.random.normal(key, (50_000,))
-    np.testing.assert_allclose(np.asarray(rotate_pallas(x, key)),
-                               np.asarray(rotate(x, key)), atol=1e-4)
-    y = rotate_pallas(x, key)
-    np.testing.assert_allclose(
-        np.asarray(rotate_pallas(y, key, inverse=True)[:50_000]),
-        np.asarray(x), atol=1e-4)
+    y = rotate_pallas(x, key, interpret=True)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(rotate(x, key)),
+                               atol=1e-4)
+    back = rotate_pallas(y, key, inverse=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(back[:50_000]), np.asarray(x),
+                               atol=1e-4)
 
 
 @pytest.mark.parametrize("d,bits", [(1024, 4), (8192, 8), (4096, 12),
@@ -50,11 +50,11 @@ def test_lattice_kernels_match_ref(d, bits):
     y = jax.random.normal(key, (d,)) * 2.0
     u = jax.random.uniform(jax.random.fold_in(key, 1), (d,))
     gamma = 0.02
-    codes = lattice_encode(y, u, gamma, bits=bits)
+    codes = lattice_encode(y, u, gamma, bits=bits, interpret=True)
     codes_ref = ref.lattice_encode_ref(y, u, gamma, bits)
     assert bool(jnp.all(codes == codes_ref))
     w = y + 0.001 * jax.random.normal(jax.random.fold_in(key, 2), (d,))
-    out = lattice_decode(codes, w, gamma, bits=bits)
+    out = lattice_decode(codes, w, gamma, bits=bits, interpret=True)
     out_ref = ref.lattice_decode_ref(codes_ref, w, gamma, bits)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                atol=1e-6)
@@ -87,13 +87,27 @@ def test_fused_encode_matches_vmapped_oracle(d, s, bits):
     d_pad = pad_len(d)
     x_pad = jnp.pad(x, ((0, 0), (0, d_pad - d)))
     y_rot, codes = fused_encode(x_pad, signs, u, gammas, bits=bits,
-                                want_rotated=True)
+                                want_rotated=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(y_rot), np.asarray(y_rows),
+                               atol=1e-4)
     codes_ref = jnp.stack([
         ref.lattice_encode_ref(y_rows[i], u[i], gammas[i], bits)
         for i in range(s)])
-    np.testing.assert_allclose(np.asarray(y_rot), np.asarray(y_rows),
-                               atol=1e-4)
-    assert float(jnp.mean((codes == codes_ref).astype(jnp.float32))) == 1.0
+    # the oracle's rotation sums in another order, so its coordinates may
+    # differ from the kernel's in the last ulps. A code may then differ only
+    # where the oracle's y/γ + u lies within a few ulps of an integer (a
+    # floor boundary), by one step, and rarely.
+    codes, codes_ref = np.asarray(codes), np.asarray(codes_ref)
+    miss = codes != codes_ref
+    t = (np.asarray(y_rows) / np.asarray(gammas)[:, None]
+         + np.asarray(u)).astype(np.float32)
+    scale = np.maximum(np.abs(np.asarray(y_rows) / np.asarray(gammas)[:, None]),
+                       1.0).astype(np.float32)
+    near = np.abs(t - np.round(t)) <= 8 * np.spacing(scale)
+    assert np.all(near[miss]), "a code differs away from a floor boundary"
+    step = (codes.astype(np.int64) - codes_ref) % (1 << bits)
+    assert np.all(np.isin(step[miss], (1, (1 << bits) - 1)))
+    assert miss.mean() <= 1e-3, miss.mean()
 
 
 @pytest.mark.parametrize("d,s,bits", [(1000, 3, 4), (5000, 4, 8),
@@ -104,7 +118,7 @@ def test_snap_codes_matches_vmapped_oracle(d, s, bits):
     codes = jnp.stack([ref.lattice_encode_ref(y_rows[i], u[i], gammas[i],
                                               bits) for i in range(s)])
     w = y_rows[0:1] + 0.001   # shared rotated reference, broadcast over s
-    out = snap_codes(codes, w, gammas, bits=bits)
+    out = snap_codes(codes, w, gammas, bits=bits, interpret=True)
     exp = jnp.stack([ref.lattice_decode_ref(codes[i], w[0], gammas[i], bits)
                      for i in range(s)])
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-6)
@@ -123,7 +137,8 @@ def test_fused_decode_matches_composed_oracle(d, s, bits):
     refs = x[0][None] + 0.002 * jax.random.normal(
         jax.random.fold_in(key, 3), (s, d))
     refs_pad = jnp.pad(refs, ((0, 0), (0, d_pad - d)))
-    out = fused_decode(codes, refs_pad, signs, gamma, bits=bits)[:, :d]
+    out = fused_decode(codes, refs_pad, signs, gamma, bits=bits,
+                       interpret=True)[:, :d]
     exp = jnp.stack([
         rotate(ref.lattice_decode_ref(codes[0], rotate(refs[i], krot),
                                       gamma[0], bits),
@@ -138,11 +153,11 @@ def test_fused_rotate_roundtrip_batched():
     signs = _signs(key, pad_len(d))
     x = jax.random.normal(jax.random.fold_in(key, 1), (s, d))
     x_pad = jnp.pad(x, ((0, 0), (0, pad_len(d) - d)))
-    y = fused_rotate(x_pad, signs)
+    y = fused_rotate(x_pad, signs, interpret=True)
     np.testing.assert_allclose(
         np.asarray(jnp.stack([rotate(x[i], key) for i in range(s)])),
         np.asarray(y), atol=1e-4)
-    back = fused_rotate(y, signs, inverse=True)[:, :d]
+    back = fused_rotate(y, signs, inverse=True, interpret=True)[:, :d]
     np.testing.assert_allclose(np.asarray(back), np.asarray(x), atol=1e-4)
 
 
@@ -160,7 +175,7 @@ def test_flash_attention_sweep(b, t, h, kv, dh, window, cap):
     k = jax.random.normal(ks[1], (b, t, kv, dh), jnp.float32)
     v = jax.random.normal(ks[2], (b, t, kv, dh), jnp.float32)
     out = flash_attention(q, k, v, causal=True, window=window, softcap=cap,
-                          block_q=64, block_k=64)
+                          block_q=64, block_k=64, interpret=True)
     exp = ref.flash_attention_ref(q, k, v, causal=True, window=window,
                                   softcap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5)
@@ -172,7 +187,8 @@ def test_flash_attention_bf16():
     q = jax.random.normal(ks[0], (1, 128, 4, 64)).astype(jnp.bfloat16)
     k = jax.random.normal(ks[1], (1, 128, 2, 64)).astype(jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, 128, 2, 64)).astype(jnp.bfloat16)
-    out = flash_attention(q, k, v, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, block_q=64, block_k=64,
+                          interpret=True)
     exp = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), atol=3e-2)
@@ -192,5 +208,6 @@ def test_flash_attention_matches_model_attention():
     k = jax.random.normal(ks[1], (b, t, cfg.n_kv_heads, cfg.head_dim))
     v = jax.random.normal(ks[2], (b, t, cfg.n_kv_heads, cfg.head_dim))
     np.testing.assert_allclose(
-        np.asarray(flash_attention(q, k, v, block_q=64, block_k=64)),
+        np.asarray(flash_attention(q, k, v, block_q=64, block_k=64,
+                          interpret=True)),
         np.asarray(attention_prefill(cfg, spec, q, k, v)), atol=2e-5)

@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # seed container has no hypothesis wheel
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_reduced
 from repro.configs.base import LayerSpec, MambaConfig

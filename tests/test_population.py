@@ -38,10 +38,7 @@ from repro.fed.population import DENSE_SAMPLE_MAX, lazy_h_steps_per_client
 from repro.models.mlp import init_mlp_classifier, mlp_loss
 from repro.utils.tree import tree_flatten_vector
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # seed container has no hypothesis wheel
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 GOLDEN_PATH = os.path.join(os.path.dirname(__file__), "golden_pr3.npz")
