@@ -196,8 +196,8 @@ def precompile(clog, jobs):
 
 
 def _lower_round(alg, state, data, key):
-    # round is a jitted method: lower it with the instance as its first arg
-    return type(alg).round.lower(alg, state, data, key)
+    # _round is a jitted method: lower it with the instance as its first arg
+    return type(alg)._round.lower(alg, state, data, key)
 
 
 # ---------------------------------------------------------------------------

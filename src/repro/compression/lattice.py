@@ -32,7 +32,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compression.rotation import DEFAULT_BLOCK, _signs, pad_len
+from repro.compression.rotation import DEFAULT_BLOCK, _signs, dither, pad_len
 from repro.compression.pipeline import (GAMMA_NORM_FLOOR, coord_bound,
                                         get_backend, wrap_gamma)
 
@@ -89,7 +89,7 @@ class LatticeQuantizer:
                             * GAMMA_NORM_FLOOR)
         krot, krnd = jax.random.split(key)
         signs = _signs(krot, d_pad)
-        u = jax.random.uniform(krnd, (d_pad,), jnp.float32)
+        u = dither(krnd, (d_pad,))
         x2 = jnp.pad(x.astype(jnp.float32), (0, d_pad - d))[None]
         codes = self._ops().encode(x2, signs, u[None], gamma[None],
                                    bits=self.bits, block=self.block,
@@ -131,7 +131,7 @@ class QSGDQuantizer:
     def encode(self, key, x: jnp.ndarray, dist_hint=None):
         norm = jnp.linalg.norm(x) + 1e-12
         y = jnp.abs(x) / norm * self.levels
-        u = jax.random.uniform(key, x.shape, jnp.float32)
+        u = dither(key, x.shape)
         q = jnp.floor(y + u) * jnp.sign(x)
         return LatticeMsg(codes=q.astype(jnp.int32), gamma=norm)
 

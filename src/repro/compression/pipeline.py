@@ -60,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.provenance import wire_mark
-from repro.compression.rotation import (DEFAULT_BLOCK, _signs,
+from repro.compression.rotation import (DEFAULT_BLOCK, _signs, dither,
                                         hadamard_matrix, pad_len)
 from repro.kernels.exchange import (block_geometry, fused_decode,
                                     fused_encode, fused_rotate, pack_codes,
@@ -358,11 +358,9 @@ class ExchangePipeline:
     def _round_randomness(self, key, s: int, d: int):
         d_pad = pad_len(d, self.block)
         signs = self.signs_for(jax.random.fold_in(key, 0), d)
-        u_srv = jax.random.uniform(jax.random.fold_in(key, 1), (1, d_pad),
-                                   jnp.float32)
+        u_srv = dither(jax.random.fold_in(key, 1), (1, d_pad))
         k_cl = jax.random.split(jax.random.fold_in(key, 2), s)
-        u_cl = jax.vmap(
-            lambda k: jax.random.uniform(k, (d_pad,), jnp.float32))(k_cl)
+        u_cl = jax.vmap(lambda k: dither(k, (d_pad,)))(k_cl)
         return signs, u_cl, u_srv
 
     # ------------------------------------------------------------------

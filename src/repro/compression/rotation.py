@@ -17,10 +17,18 @@ import numpy as np
 
 from repro.kernels.geometry import (DEFAULT_BLOCK, block_size, factor,
                                     hadamard_matrix, pad_len)
+from repro.utils.spans import NOISE
 
 
 def _signs(key, n):
-    return jax.random.rademacher(key, (n,), dtype=jnp.float32)
+    with jax.named_scope(NOISE):
+        return jax.random.rademacher(key, (n,), dtype=jnp.float32)
+
+
+def dither(key, shape):
+    """A stochastic quantizer's uniform rounding offsets, in [0, 1)."""
+    with jax.named_scope(NOISE):
+        return jax.random.uniform(key, shape, jnp.float32)
 
 
 def rotate(x: jnp.ndarray, key, block: int = DEFAULT_BLOCK,

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 
 from repro.analysis.provenance import wire_mark
 from repro.kernels.exchange import block_geometry
-from repro.compression.rotation import pad_len
+from repro.compression.rotation import dither, pad_len
 
 
 class WireBudget(NamedTuple):
@@ -162,7 +162,7 @@ def scatter_encode_gather(pipe, wire, vec_rot, ref_rot, gammas, key, n: int):
     shards = vec_rot.reshape(n, d_sh)
     gam_row = jnp.broadcast_to(jnp.asarray(gammas, jnp.float32).reshape(-1),
                                (n,))
-    u = jax.random.uniform(key, shards.shape, jnp.float32)
+    u = dither(key, shards.shape)
     codes = pipe.quantize(shards, u, gam_row, wire)
     dec = pipe.snap(codes, ref_rot.reshape(n, d_sh), gam_row, wire)
     return dec.reshape(1, d_pad), codes
@@ -321,7 +321,7 @@ class ReduceScatterSum:
         shard = jax.lax.psum_scatter(qy_own, client_axis,
                                      scatter_dimension=qy_own.ndim - 1,
                                      tiled=True)            # (1, d_sh)
-        u = jax.random.uniform(key, shard.shape, jnp.float32)
+        u = dither(key, shard.shape)
         codes_sh = pipe.quantize(shard, u, gam_rs, wire)    # (1, d_sh//pack)
         # the wire: packed integer codes + the γ-shards row, NOT fp32. The
         # gather moves the codes in their declared storage container (the

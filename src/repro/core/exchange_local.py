@@ -55,6 +55,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.compression.codecs import is_lattice_family
 from repro.compression.pipeline import ExchangePipeline, LatticeWire
+from repro.compression.rotation import dither
 from repro.utils.compat import shard_map
 from repro.utils.tree import fold_in_str
 
@@ -124,9 +125,8 @@ def make_shardlocal_exchange(quant_up, quant_down, mesh,
                           model_axes) + 1e-8
         gam_up = pipe.gammas(h_up[None], jnp.linalg.norm(y)[None], d,
                              wire_up)
-        u_up = jax.random.uniform(
-            jax.random.fold_in(jax.random.split(k_up)[1], kk_cl),
-            (1, d_pad), jnp.float32)
+        u_up = dither(jax.random.fold_in(jax.random.split(k_up)[1], kk_cl),
+                      (1, d_pad))
         y_rot, codes = pipe.rotate_encode(y[None], signs, u_up, gam_up,
                                           wire=wire_up)
         srv_rot = pipe.rotate(srv[None], signs)
@@ -164,8 +164,7 @@ def make_shardlocal_exchange(quant_up, quant_down, mesh,
             h_dn = jax.lax.pmax(h_dn, client_axis)
         gam_dn = pipe.gammas(2.0 * h_dn[None] + 1e-8,
                              jnp.linalg.norm(srv)[None], d, wire_dn)
-        u_dn = jax.random.uniform(jax.random.split(k_dn)[1], (1, d_pad),
-                                  jnp.float32)
+        u_dn = dither(jax.random.split(k_dn)[1], (1, d_pad))
         codes_dn = pipe.rotate_encode(srv[None], signs, u_dn, gam_dn,
                                       want_rotated=False, wire=wire_dn)
         qx_rot = pipe.snap(codes_dn, y_rot, gam_dn, wire_dn)

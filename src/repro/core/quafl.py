@@ -81,6 +81,7 @@ from repro.fed.clock import (client_speeds, expected_steps,  # noqa: F401
 from repro.fed.population import (Population, build_population, gather_rows,
                                   resolve_participation, scatter_rows,
                                   shard_population, with_rows)
+from repro.utils.spans import EXCHANGE, LOCAL_STEPS, POPULATION
 from repro.utils.tree import (tree_flatten_vector, tree_unflatten_vector)
 
 
@@ -239,83 +240,87 @@ class QuAFL:
         # participation spec on the clock: who answers this round's poll.
         # Everything below touches the population only through the sampled
         # rows — O(s·d), independent of n.
-        lam_row = state.pop.rows["lam"]
-        idx = self.part.sample(k_sel, state.t, n, s, lam_row)
-        got = gather_rows(state.pop, idx)
-        elapsed = state.sim_time + fed.swt + fed.sit - got["last_time"]
-        h_steps = self.part.h_steps(k_h, idx, got["lam"], elapsed,
-                                    fed.local_steps)
-
+        with jax.named_scope(POPULATION):
+            idx = self.part.sample(k_sel, state.t, n, s, state.pop.rows["lam"])
+            got = gather_rows(state.pop, idx)
+            data_s = jax.tree_util.tree_map(lambda a: a[idx], data)
         cl = got["model"]                                        # (s, d)
-        data_s = jax.tree_util.tree_map(lambda a: a[idx], data)
-        keys = jax.random.split(k_loc, s)
-        h_tilde = jax.vmap(self._local_progress)(cl, data_s, h_steps, keys)
-        eta_i = self._eta_j[idx][:, None]
-        prog = fed.lr * eta_i * h_tilde                          # η·η_i·h̃
-        Y = cl - prog                                            # (s, d)
+        with jax.named_scope(LOCAL_STEPS):
+            elapsed = state.sim_time + fed.swt + fed.sit - got["last_time"]
+            h_steps = self.part.h_steps(k_h, idx, got["lam"], elapsed,
+                                        fed.local_steps)
+            keys = jax.random.split(k_loc, s)
+            h_tilde = jax.vmap(self._local_progress)(cl, data_s, h_steps,
+                                                     keys)
+        with jax.named_scope(EXCHANGE):
+            eta_i = self._eta_j[idx][:, None]
+            prog = fed.lr * eta_i * h_tilde                      # η·η_i·h̃
+            Y = cl - prog                                        # (s, d)
 
-        # --- quantized exchange (shared per-interaction keys) -----------
-        prog_norm = jnp.linalg.norm(prog, axis=1)
-        hints_up = prog_norm + state.srv_dist_est + 1e-8
-        cs_new = None          # sampled clients' updated EF rows (if any)
+            # --- quantized exchange (shared per-interaction keys) -------
+            prog_norm = jnp.linalg.norm(prog, axis=1)
+            hints_up = prog_norm + state.srv_dist_est + 1e-8
+            cs_new = None          # sampled clients' updated EF rows (if any)
 
-        if self.pipeline is not None:
-            # rotated-space engine: one shared rotation per round, all
-            # encode/decode/averaging in rotated coordinates (s+1 forward,
-            # s+1 inverse full-model rotations — audited in the tests).
-            # The per-direction codecs parameterize the wire (bit-width,
-            # sub-byte packing, per-client levels) without touching the
-            # rotation structure.
-            fn = (self.pipeline.quafl_round
-                  if self.exchange_impl == "pipeline"
-                  else self.pipeline.quafl_round_reference)
-            server_new, cl_new, hint_srv, rel_err = fn(
-                k_q, state.server, Y, hints_up, avg_mode=self.avg_mode,
-                up=self.codec_up.wire(idx), down=self.codec_down.wire())
-        else:
-            # scalar / identity / top-k: no rotation to restructure around
-            kq_cl = jax.random.split(jax.random.fold_in(k_q, 1), s)
-
-            if self._thread_ef:
-                cs = got["codec_up"]            # gathered EF rows (s, ...)
-
-                def enc_dec_up(y, kk, hint, cs_i):
-                    msg, cs_i = self.codec_up.encode_stateful(
-                        kk, y, hint, cs_i)
-                    return self.codec_up.decode(kk, msg, state.server), cs_i
-
-                QY, cs_new = jax.vmap(enc_dec_up)(Y, kq_cl, hints_up, cs)
+            if self.pipeline is not None:
+                # rotated-space engine: one shared rotation per round, all
+                # encode/decode/averaging in rotated coordinates (s+1 forward,
+                # s+1 inverse full-model rotations — audited in the tests).
+                # The per-direction codecs parameterize the wire (bit-width,
+                # sub-byte packing, per-client levels) without touching the
+                # rotation structure.
+                fn = (self.pipeline.quafl_round
+                      if self.exchange_impl == "pipeline"
+                      else self.pipeline.quafl_round_reference)
+                server_new, cl_new, hint_srv, rel_err = fn(
+                    k_q, state.server, Y, hints_up, avg_mode=self.avg_mode,
+                    up=self.codec_up.wire(idx), down=self.codec_down.wire())
             else:
-                def enc_dec_up(y, kk, hint):
-                    msg = self.codec_up.encode(kk, y, hint)
-                    return self.codec_up.decode(kk, msg, state.server)
+                # scalar / identity / top-k: no rotation to restructure around
+                kq_cl = jax.random.split(jax.random.fold_in(k_q, 1), s)
 
-                QY = jax.vmap(enc_dec_up)(Y, kq_cl, hints_up)    # (s, d)
+                if self._thread_ef:
+                    cs = got["codec_up"]            # gathered EF rows (s, ...)
 
-            # server -> clients: ONE encode, per-client decode vs own X^i
-            kq_srv = jax.random.fold_in(k_q, 0)
-            hint_srv = (jnp.max(jnp.linalg.norm(QY - state.server[None],
-                                                axis=1)) + 1e-8)
-            msg_srv = self.codec_down.encode(kq_srv, state.server, hint_srv)
-            QX = jax.vmap(
-                lambda ref: self.codec_down.decode(kq_srv, msg_srv,
-                                                   ref))(cl)
+                    def enc_dec_up(y, kk, hint, cs_i):
+                        msg, cs_i = self.codec_up.encode_stateful(
+                            kk, y, hint, cs_i)
+                        return (self.codec_up.decode(kk, msg, state.server),
+                                cs_i)
 
-            # --- averaging ------------------------------------------------
-            if self.avg_mode == "both":
-                server_new = (state.server + jnp.sum(QY, 0)) / (s + 1)
-                cl_new = QX / (s + 1) + s * Y / (s + 1)
-            elif self.avg_mode == "server_only":
-                server_new = (state.server + jnp.sum(QY, 0)) / (s + 1)
-                cl_new = QX
-            elif self.avg_mode == "client_only":
-                server_new = jnp.mean(QY, 0)
-                cl_new = QX / (s + 1) + s * Y / (s + 1)
-            else:  # 'none' — plain replacement both sides
-                server_new = jnp.mean(QY, 0)
-                cl_new = QX
-            rel_err = jnp.mean(jnp.linalg.norm(QY - Y, axis=1)
-                               / (jnp.linalg.norm(Y, axis=1) + 1e-9))
+                    QY, cs_new = jax.vmap(enc_dec_up)(Y, kq_cl, hints_up, cs)
+                else:
+                    def enc_dec_up(y, kk, hint):
+                        msg = self.codec_up.encode(kk, y, hint)
+                        return self.codec_up.decode(kk, msg, state.server)
+
+                    QY = jax.vmap(enc_dec_up)(Y, kq_cl, hints_up)    # (s, d)
+
+                # server -> clients: ONE encode, per-client decode vs own X^i
+                kq_srv = jax.random.fold_in(k_q, 0)
+                hint_srv = (jnp.max(jnp.linalg.norm(QY - state.server[None],
+                                                    axis=1)) + 1e-8)
+                msg_srv = self.codec_down.encode(kq_srv, state.server,
+                                                 hint_srv)
+                QX = jax.vmap(
+                    lambda ref: self.codec_down.decode(kq_srv, msg_srv,
+                                                       ref))(cl)
+
+                # --- averaging --------------------------------------------
+                if self.avg_mode == "both":
+                    server_new = (state.server + jnp.sum(QY, 0)) / (s + 1)
+                    cl_new = QX / (s + 1) + s * Y / (s + 1)
+                elif self.avg_mode == "server_only":
+                    server_new = (state.server + jnp.sum(QY, 0)) / (s + 1)
+                    cl_new = QX
+                elif self.avg_mode == "client_only":
+                    server_new = jnp.mean(QY, 0)
+                    cl_new = QX / (s + 1) + s * Y / (s + 1)
+                else:  # 'none' — plain replacement both sides
+                    server_new = jnp.mean(QY, 0)
+                    cl_new = QX
+                rel_err = jnp.mean(jnp.linalg.norm(QY - Y, axis=1)
+                                   / (jnp.linalg.norm(Y, axis=1) + 1e-9))
 
         # bit accounting, computed BY the codecs' wire formats: s uplink
         # messages (per-client widths under a grouped codec) + ONE downlink
@@ -333,9 +338,10 @@ class QuAFL:
         updates = {"model": cl_new, "last_time": new_time}
         if cs_new is not None:
             updates["codec_up"] = cs_new
+        with jax.named_scope(POPULATION):
+            pop = scatter_rows(state.pop, idx, updates)
         state = QuaflState(
-            server=server_new,
-            pop=scatter_rows(state.pop, idx, updates),
+            server=server_new, pop=pop,
             t=state.t + 1, sim_time=new_time,
             bits_up=state.bits_up + bits_up,
             bits_down=state.bits_down + bits_down,

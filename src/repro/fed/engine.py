@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.fed.api import FedAlgorithm
+from repro.utils.spans import CHUNK
 
 
 @runtime_checkable
@@ -315,11 +316,12 @@ class RoundEngine:
         walk already discard it in favour of the returned state. The
         (tiny, caller-supplied) ``key`` is NOT donated.
         """
-        custom = getattr(self.alg, "scan_rounds", None)
-        if custom is not None:
-            return custom(state, data, key, length)
-        state, key, carry_out = self._commit_carry(state, key)
-        return self.chunk_fn(length, carry_out)(state, data, key)
+        with jax.profiler.TraceAnnotation(CHUNK):
+            custom = getattr(self.alg, "scan_rounds", None)
+            if custom is not None:
+                return custom(state, data, key, length)
+            state, key, carry_out = self._commit_carry(state, key)
+            return self.chunk_fn(length, carry_out)(state, data, key)
 
     # -- analyzer hooks (repro.analysis) ------------------------------------
 

@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.fed.api import FedAlgorithm, normalize_metrics
 from repro.fed.engine import RoundEngine, supports_scan
+from repro.utils.spans import EVAL, SYNC
 
 
 @dataclass
@@ -92,16 +93,18 @@ class _Recorder:
 
     def run_eval(self, r: int):
         t_e = time.time()
-        res = self.eval_fn(self.alg.eval_params(self.state))
+        with jax.profiler.TraceAnnotation(EVAL):
+            res = self.eval_fn(self.alg.eval_params(self.state))
         self.trace.eval_time_s += time.time() - t_e
         self.evaled_round = r
         return res if isinstance(res, dict) else {"eval": res}
 
     def record(self, r: int, metrics, bits_up, bits_down, do_eval: bool):
-        row = dict(normalize_metrics(metrics), round=r,
-                   bits_up_total=float(bits_up),
-                   bits_down_total=float(bits_down),
-                   wall_time_s=time.time() - self.t0)
+        with jax.profiler.TraceAnnotation(SYNC):
+            row = dict(normalize_metrics(metrics), round=r,
+                       bits_up_total=float(bits_up),
+                       bits_down_total=float(bits_down),
+                       wall_time_s=time.time() - self.t0)
         if do_eval and self.eval_fn is not None:
             row.update(self.run_eval(r))
         self.trace.rows.append(row)
@@ -195,9 +198,11 @@ def simulate(alg: FedAlgorithm, params0, data, key, *,
         bits_down = bits_down + metrics.get("bits_down", 0.0)
         done = rounds is not None and r >= rounds
         if not done and until_sim_time is not None:
-            done = float(metrics.get("sim_time", 0.0)) >= until_sim_time
+            with jax.profiler.TraceAnnotation(SYNC):
+                done = float(metrics.get("sim_time", 0.0)) >= until_sim_time
         if not done and until_bits is not None:
-            done = float(bits_up) + float(bits_down) >= until_bits
+            with jax.profiler.TraceAnnotation(SYNC):
+                done = float(bits_up) + float(bits_down) >= until_bits
         do_eval = done or (eval_every and r % eval_every == 0)
         if do_eval or (record_every and r % record_every == 0):
             rec.record(r, metrics, bits_up, bits_down, do_eval)
@@ -259,7 +264,8 @@ def _simulate_scanned(alg, params0, data, key, *, rounds, until_sim_time,
         n = min(n, scan_chunk)
         key, state, stacked = engine.run_chunk(state, data, key, n)
         rec.state = state
-        host = jax.device_get(stacked)   # the chunk's single host sync
+        with jax.profiler.TraceAnnotation(SYNC):
+            host = jax.device_get(stacked)   # the chunk's single host sync
         for j in range(n):
             rj = r + j + 1
             mj = {k: v[j] for k, v in host.items()}

@@ -41,6 +41,7 @@ from repro.configs.base import FedConfig, ModelConfig, ShapeConfig
 from repro.core.transport import tree_bits
 from repro.launch.steps import (TrainState, build_train_step, fed_mode_for,
                                 n_slots_for)
+from repro.utils.spans import LOCAL_STEPS, ROUND
 
 
 class SpmdState(NamedTuple):
@@ -145,8 +146,9 @@ class SpmdAlgorithm:
         n, K = self.n_slots, fed.local_steps
         k_b, k_r = jax.random.split(key)
         pool = data["tokens"].shape[1]
-        idx = jax.random.randint(k_b, (n, K, self.batch), 0, pool)
-        toks = jax.vmap(lambda p, ix: p[ix])(data["tokens"][:n], idx)
+        with jax.named_scope(LOCAL_STEPS):
+            idx = jax.random.randint(k_b, (n, K, self.batch), 0, pool)
+            toks = jax.vmap(lambda p, ix: p[ix])(data["tokens"][:n], idx)
         train, m = self._step(state.train, {"tokens": toks},
                               jax.random.key_data(k_r))
 
@@ -179,8 +181,12 @@ class SpmdAlgorithm:
     # donate_argnums, folded into the protocol entry point); the scanned
     # engine drives device_round instead, where scan carries the buffers
     @partial(jax.jit, static_argnums=0, donate_argnums=1)
-    def round(self, state: SpmdState, data, key):
+    def _round(self, state: SpmdState, data, key):
         return self.device_round(state, data, key)
+
+    def round(self, state: SpmdState, data, key):
+        with jax.profiler.TraceAnnotation(ROUND):
+            return self._round(state, data, key)
 
     def eval_params(self, state: SpmdState):
         return state.train.server

@@ -33,6 +33,7 @@ from repro.launch.specs import (abstract_cache, enc_len_for, input_axes,
 from repro.models.model import (abstract_lm, decode_step, forward, init_cache,
                                 lm_loss)
 from repro.sharding.rules import pspec_for, rules_for_mode
+from repro.utils.spans import EXCHANGE, LOCAL_STEPS
 
 # architectures too large for per-data-slice client replicas get cohort mode
 FED_MODE: Dict[str, str] = {
@@ -138,7 +139,8 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
             p = {k: (p[k] - lr * act * g[k].astype(p[k].dtype)) for k in p}
             return p, None
 
-        pK, _ = jax.lax.scan(step, cp, jnp.arange(K))
+        with jax.named_scope(LOCAL_STEPS):
+            pK, _ = jax.lax.scan(step, cp, jnp.arange(K))
         # Y = X - η·η_i·h̃ = (1-η_i)·X + η_i·X_K   (h̃ = (X - X_K)/η)
         return pK
 
@@ -158,10 +160,11 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
     def slot_progress(cp_i, toks_i, fe_i, h_i, eta, key_i):
         pK = local_round(cp_i, toks_i, fe_i, h_i, key_i)
         # Y = X − η·η_i·h̃ = (1−η_i)·X + η_i·X_K
-        Y_i = {k: ((1.0 - eta) * cp_i[k].astype(jnp.float32)
-                   + eta * pK[k].astype(jnp.float32)).astype(cp_i[k].dtype)
-               for k in cp_i}
-        return Y_i, leaf_dist(Y_i, cp_i)
+        with jax.named_scope(EXCHANGE):
+            Y_i = {k: ((1.0 - eta) * cp_i[k].astype(jnp.float32)
+                       + eta * pK[k].astype(jnp.float32)).astype(
+                           cp_i[k].dtype) for k in cp_i}
+            return Y_i, leaf_dist(Y_i, cp_i)
 
     def slot_encode(Y_i, hints_i, key_i):
         return tree_encode(quant_up, key_i, Y_i, hints_i)
@@ -180,9 +183,10 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
         k_h, k_q, k_loc = jax.random.split(key, 3)
         toks = batch["tokens"]                   # (n_slots, K, b, t)
         fe = batch.get("frontend")
-        h_steps = jnp.minimum(
-            jax.random.poisson(k_h, jnp.asarray(lam) * (fed.swt + fed.sit),
-                               (n_slots,)), K).astype(jnp.int32)
+        with jax.named_scope(LOCAL_STEPS):
+            h_steps = jnp.minimum(jax.random.poisson(
+                k_h, jnp.asarray(lam) * (fed.swt + fed.sit), (n_slots,)),
+                K).astype(jnp.int32)
         etas = jnp.asarray(eta_i)
         loc_keys = jax.random.split(k_loc, n_slots)
         q_keys = jax.random.split(jax.random.fold_in(k_q, 1), n_slots)
@@ -232,54 +236,56 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
             # their servers by up to 4.9e-5, while the exchange alone, fed
             # the same Ys, agreed to 0.0
             Ys = jax.lax.optimization_barrier(Ys)
-            server_new, clients_new, qerr = ex(
-                state.server, state.clients, Ys, ex_key)
+            with jax.named_scope(EXCHANGE):
+                server_new, clients_new, qerr = ex(
+                    state.server, state.clients, Ys, ex_key)
             new_state = TrainState(server=server_new, clients=clients_new,
                                    t=state.t + 1)
             return new_state, {
                 "h_steps_mean": jnp.mean(h_steps.astype(jnp.float32)),
                 "quant_err_sq": qerr}
 
-        # ---- client -> server: Enc(Y^i), decoded against X_t -------------
-        msgs_up = vmap_slots(slot_encode)(Ys, hints_up, q_keys)
-        if transport == "code_allgather" and quantized:
-            repl = NamedSharding(mesh, P())
-            # replicate every message leaf (codes, scales, indices, ...) so
-            # any codec's wire format rides this transport
-            msgs_up = {k: jax.tree_util.tree_map(
-                lambda a: jax.lax.with_sharding_constraint(a, repl), m)
-                for k, m in msgs_up.items()}
-        QYs = jax.vmap(slot_decode_up, in_axes=(0, 0, None),
-                       spmd_axis_name=(None if transport == "code_allgather"
-                                       else spmd_axis))(
-            msgs_up, q_keys, state.server)
+        with jax.named_scope(EXCHANGE):
+            # ---- client -> server: Enc(Y^i), decoded against X_t ---------
+            msgs_up = vmap_slots(slot_encode)(Ys, hints_up, q_keys)
+            if transport == "code_allgather" and quantized:
+                repl = NamedSharding(mesh, P())
+                # replicate every message leaf (codes, scales, indices,
+                # ...) so any codec's wire format rides this transport
+                msgs_up = {k: jax.tree_util.tree_map(
+                    lambda a: jax.lax.with_sharding_constraint(a, repl), m)
+                    for k, m in msgs_up.items()}
+            up_axis = None if transport == "code_allgather" else spmd_axis
+            QYs = jax.vmap(slot_decode_up, in_axes=(0, 0, None),
+                           spmd_axis_name=up_axis)(
+                msgs_up, q_keys, state.server)
 
-        server_new = {
-            k: ((state.server[k].astype(jnp.float32)
-                 + jnp.sum(QYs[k].astype(jnp.float32), 0)) / denom
-                ).astype(state.server[k].dtype)
-            for k in state.server}
+            server_new = {
+                k: ((state.server[k].astype(jnp.float32)
+                     + jnp.sum(QYs[k].astype(jnp.float32), 0)) / denom
+                    ).astype(state.server[k].dtype)
+                for k in state.server}
 
-        # ---- server -> clients: ONE Enc(X_t), per-client decode ----------
-        hints_down = {
-            k: 2.0 * jnp.max(jax.vmap(
-                lambda q: jnp.linalg.norm(
-                    (q - state.server[k]).astype(jnp.float32).ravel()))(
-                QYs[k]))
-            for k in state.server}
-        k_srv = jax.random.fold_in(k_q, n_slots + 7)
-        msg_srv = tree_encode(quant_down, k_srv, state.server, hints_down)
+            # ---- server -> clients: ONE Enc(X_t), per-client decode ------
+            hints_down = {
+                k: 2.0 * jnp.max(jax.vmap(
+                    lambda q: jnp.linalg.norm(
+                        (q - state.server[k]).astype(jnp.float32).ravel()))(
+                    QYs[k]))
+                for k in state.server}
+            k_srv = jax.random.fold_in(k_q, n_slots + 7)
+            msg_srv = tree_encode(quant_down, k_srv, state.server, hints_down)
 
-        if unroll_slots:
-            cls = [slot_update(sl(state.clients, i), sl(Ys, i), k_srv,
-                               msg_srv, denom) for i in range(n_slots)]
-            clients_new = {k: jnp.stack([c[k] for c in cls], 0)
-                           for k in state.server}
-        else:
-            clients_new = jax.vmap(slot_update,
-                                   in_axes=(0, 0, None, None, None),
-                                   spmd_axis_name=spmd_axis)(
-                state.clients, Ys, k_srv, msg_srv, denom)
+            if unroll_slots:
+                cls = [slot_update(sl(state.clients, i), sl(Ys, i), k_srv,
+                                   msg_srv, denom) for i in range(n_slots)]
+                clients_new = {k: jnp.stack([c[k] for c in cls], 0)
+                               for k in state.server}
+            else:
+                clients_new = jax.vmap(slot_update,
+                                       in_axes=(0, 0, None, None, None),
+                                       spmd_axis_name=spmd_axis)(
+                    state.clients, Ys, k_srv, msg_srv, denom)
 
         qerr = sum(jnp.sum(jnp.square((QYs[k] - Ys[k]).astype(jnp.float32)))
                    for k in state.server) / n_slots

@@ -184,3 +184,41 @@ def test_hadamard_and_lattice_quant_compile(one_chip):
     text = _compile(enc_dec, _f32(one_chip, D), _f32(one_chip, D),
                     _f32(one_chip, D))
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_round_kernels_sit_in_the_exchange_scope(one_chip):
+    """Every Mosaic kernel of a whole spmd round, compiled with the
+    ``pallas`` backend, carries ``fl.exchange`` in its ``op_name``: the
+    reduction of a chip trace counts kernel time under the exchange."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.configs import get_reduced
+    from repro.configs.base import FedConfig
+    from repro.fed import make_algorithm
+    from repro.models.model import init_lm
+    from repro.utils.spans import EXCHANGE
+
+    device, = one_chip.device_set
+    mesh = Mesh(np.array([device]).reshape(1, 1), ("data", "model"))
+    cfg = get_reduced("llama3.2-1b")
+    fed = FedConfig(n_clients=1, s=1, local_steps=2, lr=0.05, bits=8,
+                    kernel_backend="pallas")
+    template = jax.eval_shape(lambda: init_lm(cfg, jax.random.PRNGKey(0))[0])
+    alg = make_algorithm("spmd", fed, loss_fn=None, template=template,
+                         batch_fn=None, cfg=cfg, batch=2, seq=16, mesh=mesh)
+    repl = NamedSharding(mesh, PartitionSpec())
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+        jax.eval_shape(alg.init, template))
+    data = {"tokens": jax.ShapeDtypeStruct((1, 8, 16), jnp.int32,
+                                           sharding=repl)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
+    text = type(alg)._round.lower(alg, state, data, key).compile().as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]*)"', text)
+    assert names and len(names) == text.count(
+        'custom_call_target="tpu_custom_call"')
+    assert all(f"/{EXCHANGE}/" in n for n in names), names
