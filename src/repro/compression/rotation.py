@@ -11,6 +11,8 @@ the sign diagonal, so the inverse is D H_b / sqrt(b).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,15 +22,34 @@ from repro.kernels.geometry import (DEFAULT_BLOCK, block_size, factor,
 from repro.utils.spans import NOISE
 
 
+_LANES = 128
+
+
+def _dense(shape):
+    """The shape to draw ``shape``'s elements at: ``(size // 128, 128)``
+    when the size is a multiple of 128, else ``shape`` itself.
+
+    A draw at ``(n,)`` becomes ``(1, n)`` under a one-slot ``vmap``, which
+    a TPU tiles ``T(1,128)``: one of a vreg's 8 sublanes, at 8x the time
+    of the same draw tiled ``T(8,128)``. jax.random draws each element
+    from its row-major index, whatever the shape, so a draw at either
+    shape gives the same values bit for bit.
+    """
+    size = math.prod(shape)
+    return shape if size % _LANES else (size // _LANES, _LANES)
+
+
 def _signs(key, n):
     with jax.named_scope(NOISE):
-        return jax.random.rademacher(key, (n,), dtype=jnp.float32)
+        return jax.random.rademacher(key, _dense((n,)),
+                                     dtype=jnp.float32).reshape(n)
 
 
 def dither(key, shape):
     """A stochastic quantizer's uniform rounding offsets, in [0, 1)."""
     with jax.named_scope(NOISE):
-        return jax.random.uniform(key, shape, jnp.float32)
+        return jax.random.uniform(key, _dense(shape),
+                                  jnp.float32).reshape(shape)
 
 
 def rotate(x: jnp.ndarray, key, block: int = DEFAULT_BLOCK,

@@ -11,17 +11,21 @@ one process may load the TPU library at a time, and every pytest worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.compression.lattice import LatticeQuantizer
+from repro.compression.rotation import _signs, dither
 from repro.kernels.exchange import (fused_decode, fused_encode, fused_rotate,
                                     quantize_codes, snap_codes)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.hadamard import hadamard_blocks
 from repro.kernels.lattice_quant import lattice_decode, lattice_encode
+from repro.utils.spans import NOISE
 
 D = 1 << 20             # 64 Hadamard blocks of 128 x 128
 
@@ -158,6 +162,32 @@ def test_vmapped_leaf_exchange_compiles(one_chip):
                     _f32(one_chip, 3, 1, D), _f32(one_chip, 3, 1),
                     _f32(one_chip, 1, D))
     assert text.count("tpu_custom_call") >= 2
+
+
+def _noise_ops(text, result):
+    """Lines of the compiled text in the noise scope whose result type
+    starts with ``result`` (a regex)."""
+    return [ln for ln in text.splitlines()
+            if re.search(rf"= \(?{result}", ln) and NOISE in ln]
+
+
+@pytest.mark.parametrize("what", ["draws", "encode"])
+def test_vmapped_noise_draws_are_sublane_dense(one_chip, what):
+    """Under a one-slot ``vmap`` a draw at (D,) becomes (1, D), which the
+    TPU tiles T(1,128): one of a vreg's 8 sublanes. The signs and dither
+    are drawn as (1, D/128, 128) instead, tiled T(8,128), and the encode
+    kernel reads them as they are."""
+    key = jax.ShapeDtypeStruct((1, 2), jnp.uint32, sharding=one_chip)
+    if what == "draws":
+        text = _compile(jax.vmap(lambda k: (dither(k, (D,)), _signs(k, D))),
+                        key)
+    else:
+        q = LatticeQuantizer(backend="pallas")
+        text = _compile(jax.vmap(q.encode), key, _f32(one_chip, 1, D),
+                        _f32(one_chip, 1))
+        assert "tpu_custom_call" in text
+    assert not _noise_ops(text, rf"f32\[1,{D}\]\{{1,0:T\(1,128\)")
+    assert _noise_ops(text, rf"f32\[1,{D // 128},128\]\{{2,1,0:T\(8,128\)")
 
 
 def test_flash_attention_compiles_at_llama_heads(one_chip):
