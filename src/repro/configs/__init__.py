@@ -14,7 +14,7 @@ from typing import Dict, List
 from repro.configs.base import (  # noqa: F401 (re-export)
     ATTN_CHUNKED, ATTN_FULL, ATTN_MLA, ATTN_SLIDING, KIND_ATTN, KIND_MAMBA,
     FedConfig, LayerSpec, MambaConfig, MeshConfig, MLAConfig, ModelConfig,
-    MoEConfig, ShapeConfig, SHAPES, TrainConfig,
+    MoEConfig, ShapeConfig, SHAPES, TrainConfig, YarnScaling,
 )
 
 # arch id -> module name
@@ -22,6 +22,7 @@ _ARCHS: Dict[str, str] = {
     "llama4-scout-17b-a16e": "llama4_scout_17b_a16e",
     "gemma2-2b": "gemma2_2b",
     "deepseek-v2-236b": "deepseek_v2_236b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "mamba2-370m": "mamba2_370m",
     "llava-next-34b": "llava_next_34b",
     "seamless-m4t-medium": "seamless_m4t_medium",

@@ -7,6 +7,7 @@ Every assigned architecture gets one file in this package with a ``config()``
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -44,6 +45,33 @@ class MoEConfig:
     router_aux_coef: float = 0.01
     impl: str = "ragged"           # 'ragged' (lax.ragged_dot) | 'dense' (one-hot)
     capacity_factor: float = 1.25  # only for the dense impl
+    # the experts this layer holds: n_held from held_offset (0 = all); the
+    # router still scores all n_experts (expert parallelism's share)
+    n_held: int = 0
+    held_offset: int = 0
+    norm_topk_prob: bool = True    # renormalise the top-k weights to sum 1
+    # DeepSeek-V2's sequence-wise balance loss in place of the Switch-style
+    # batch loss
+    seq_aux: bool = False
+    router_f32: bool = False       # the router's logits in float32 (DeepSeek)
+    # DeepSeek-V2's device-level budget ('ragged' only): per sequence the
+    # held experts keep at most budget(t) of the pairs routed to them, those
+    # of the highest routing weight, and compute budget(t) rows whatever
+    # the routing; a training rule (a decoded token is a sequence of one);
+    # 0: every pair kept, no budget
+    device_capacity: float = 0.0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+    def budget(self, t: int) -> int:
+        """The pairs a sequence of t tokens may keep on the held experts."""
+        room = t * min(self.top_k, self.held)
+        if not self.device_capacity:
+            return room
+        return min(room, math.ceil(self.device_capacity * t * self.top_k
+                                   * self.held / self.n_experts))
 
 
 @dataclass(frozen=True)
@@ -58,11 +86,23 @@ class MambaConfig:
 
 @dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 1536
+    q_lora_rank: int = 1536        # 0: a direct query projection (no LoRA)
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling as DeepSeek-V2 states it (``rope_scaling`` of its
+    config.json, type "yarn")."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -85,6 +125,7 @@ class ModelConfig:
     mla: Optional[MLAConfig] = None
     # misc architectural knobs
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnScaling] = None   # read by MLA
     logit_softcap: float = 0.0
     attn_softcap: float = 0.0
     qk_norm: bool = False

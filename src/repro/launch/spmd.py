@@ -41,6 +41,7 @@ from repro.configs.base import FedConfig, ModelConfig, ShapeConfig
 from repro.core.transport import tree_bits
 from repro.launch.steps import (TrainState, build_train_step, fed_mode_for,
                                 n_slots_for)
+from repro.models.moe import MOE_COUNTERS
 from repro.utils.spans import LOCAL_STEPS, ROUND
 
 
@@ -172,6 +173,8 @@ class SpmdAlgorithm:
             "h_steps_mean": m["h_steps_mean"],
             "quant_err": rel,
             "quant_err_sq": m["quant_err_sq"],
+            # MoE models: the rows of each local step, summed over slots
+            **{k: m[k] for k in MOE_COUNTERS if k in m},
         }
         return SpmdState(train=train, sim_time=new_time,
                          bits_up=state.bits_up + bits_up,

@@ -32,6 +32,7 @@ from repro.launch.specs import (abstract_cache, enc_len_for, input_axes,
                                 input_specs)
 from repro.models.model import (abstract_lm, decode_step, forward, init_cache,
                                 lm_loss)
+from repro.models.moe import MOE_COUNTERS
 from repro.sharding.rules import pspec_for, rules_for_mode
 from repro.utils.spans import EXCHANGE, LOCAL_STEPS
 
@@ -124,25 +125,30 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
     eta_i = ((H.min() / H) if fed.weighted else np.ones(n_slots)).astype(
         np.float32)
 
+    # an MoE model counts its layers' routed, kept and buffer rows in each
+    # step
+    counted = MOE_COUNTERS if cfg.moe is not None else ()
+
     def local_round(cp, toks, fe, h_i, key):
-        """One client slot: up to K masked local steps. toks: (K, b, t)."""
+        """One client slot: up to K masked local steps. toks: (K, b, t).
+        Returns (X_K, {counter: (K,)})."""
         def loss_fn(p, batch):
-            loss, _ = lm_loss(cfg, p, batch, remat=remat)
-            return loss
+            loss, m = lm_loss(cfg, p, batch, remat=remat)
+            return loss, {k: m[k] for k in counted}
 
         def step(p, q):
             batch = {"tokens": toks[q]}
             if fe is not None:
                 batch["frontend"] = fe[q]
-            g = jax.grad(loss_fn)(p, batch)
+            g, rows = jax.grad(loss_fn, has_aux=True)(p, batch)
             act = (q < h_i).astype(jnp.float32)
             p = {k: (p[k] - lr * act * g[k].astype(p[k].dtype)) for k in p}
-            return p, None
+            return p, rows
 
         with jax.named_scope(LOCAL_STEPS):
-            pK, _ = jax.lax.scan(step, cp, jnp.arange(K))
+            pK, rows = jax.lax.scan(step, cp, jnp.arange(K))
         # Y = X - η·η_i·h̃ = (1-η_i)·X + η_i·X_K   (h̃ = (X - X_K)/η)
-        return pK
+        return pK, rows
 
     # vmap over client slots keeps the HLO one-body-sized; the MoE archs run
     # in cohort mode (n_slots ∈ {1, 2}) and use an unrolled loop instead, so
@@ -158,13 +164,13 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
         return jax.vmap(fn, in_axes=in_axes, spmd_axis_name=spmd_axis)
 
     def slot_progress(cp_i, toks_i, fe_i, h_i, eta, key_i):
-        pK = local_round(cp_i, toks_i, fe_i, h_i, key_i)
+        pK, rows = local_round(cp_i, toks_i, fe_i, h_i, key_i)
         # Y = X − η·η_i·h̃ = (1−η_i)·X + η_i·X_K
         with jax.named_scope(EXCHANGE):
             Y_i = {k: ((1.0 - eta) * cp_i[k].astype(jnp.float32)
                        + eta * pK[k].astype(jnp.float32)).astype(
                            cp_i[k].dtype) for k in cp_i}
-            return Y_i, leaf_dist(Y_i, cp_i)
+            return Y_i, leaf_dist(Y_i, cp_i), rows
 
     def slot_encode(Y_i, hints_i, key_i):
         return tree_encode(quant_up, key_i, Y_i, hints_i)
@@ -204,13 +210,18 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
                   for k in state.server}
             hints_up = {k: jnp.stack([p[1][k] for p in pieces], 0)
                         for k in state.server}
+            rows = {k: jnp.stack([p[2][k] for p in pieces], 0)
+                    for k in counted}
         else:
-            Ys, hints_up = vmap_slots(
+            Ys, hints_up, rows = vmap_slots(
                 lambda cp, tk, f, h, e, kk: slot_progress(cp, tk, f, h, e, kk)
             )(state.clients, toks, fe, h_steps, etas, loc_keys) \
                 if fe is not None else vmap_slots(
                 lambda cp, tk, h, e, kk: slot_progress(cp, tk, None, h, e, kk)
             )(state.clients, toks, h_steps, etas, loc_keys)
+
+        # the counters of each local step, summed over the slots
+        counters = {k: jnp.sum(v, 0) for k, v in rows.items()}
 
         # ---- shard-local exchange (§Perf): whole exchange in shard_map ----
         if transport in ("shard_local", "shard_local_codes",
@@ -243,7 +254,7 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
                                    t=state.t + 1)
             return new_state, {
                 "h_steps_mean": jnp.mean(h_steps.astype(jnp.float32)),
-                "quant_err_sq": qerr}
+                "quant_err_sq": qerr, **counters}
 
         with jax.named_scope(EXCHANGE):
             # ---- client -> server: Enc(Y^i), decoded against X_t ---------
@@ -293,7 +304,7 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
         new_state = TrainState(server=server_new, clients=clients_new,
                                t=state.t + 1)
         metrics = {"h_steps_mean": jnp.mean(h_steps.astype(jnp.float32)),
-                   "quant_err_sq": qerr}
+                   "quant_err_sq": qerr, **counters}
         return new_state, metrics
 
     state_spec, state_sh = abstract_train_state(cfg, mesh, fed_mode)

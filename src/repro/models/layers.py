@@ -1,6 +1,8 @@
 """Shared building blocks: norms, RoPE, dense MLPs, embeddings."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,18 +41,52 @@ def softcap(x, cap: float):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (DeepSeek-V2 ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_blend(freqs, head_dim: int, theta: float, s):
+    """DeepSeek-V2's YaRN frequencies: the interpolated (freqs / factor)
+    below the correction range found from ``beta_fast``/``beta_slow``, the
+    original above it, a linear ramp between."""
+    def dim_of(rotations):
+        return (head_dim * math.log(s.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(dim_of(s.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(s.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - lo)
+                   / np.float32(hi - lo), 0, 1)
+    extra = 1.0 - ramp
+    return ((freqs / np.float32(s.factor)) * (1.0 - extra)
+            + freqs * extra).astype(np.float32)
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None):
     half = head_dim // 2
-    return 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) * 2.0 / head_dim))
+    freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) * 2.0 / head_dim))
+    if scaling is not None:
+        freqs = _yarn_blend(freqs, head_dim, theta, scaling)
+    return freqs
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., t, heads, head_dim); positions: (..., t) int32."""
+def apply_rope(x, positions, theta: float, scaling=None):
+    """x: (..., t, heads, head_dim); positions: (..., t) int32. ``scaling``
+    (a ``YarnScaling``) blends the frequencies and scales cos and sin by
+    mscale(mscale) / mscale(mscale_all_dim)."""
     head_dim = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(head_dim, theta))           # (half,)
+    freqs = jnp.asarray(rope_freqs(head_dim, theta, scaling))  # (half,)
     ang = positions[..., None].astype(jnp.float32) * freqs     # (..., t, half)
     cos = jnp.cos(ang)[..., None, :]                           # (..., t, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * np.float32(m), sin * np.float32(m)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import apply_rope, rms_norm
+from repro.models.layers import apply_rope, rms_norm, yarn_mscale
+from repro.utils.spans import MLA
 
 NEG_INF = -1e30
 
@@ -21,9 +22,12 @@ def init_mla(ctx, cfg):
     m = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
     qd = m.qk_nope_dim + m.qk_rope_dim
-    ctx.param("wq_a", (d, m.q_lora_rank), ("embed", "lora"))
-    ctx.param("q_norm/scale", (m.q_lora_rank,), (None,), init="zeros")
-    ctx.param("wq_b", (m.q_lora_rank, h * qd), ("lora", "q_flat"))
+    if m.q_lora_rank:
+        ctx.param("wq_a", (d, m.q_lora_rank), ("embed", "lora"))
+        ctx.param("q_norm/scale", (m.q_lora_rank,), (None,), init="zeros")
+        ctx.param("wq_b", (m.q_lora_rank, h * qd), ("lora", "q_flat"))
+    else:
+        ctx.param("wq", (d, h * qd), ("embed", "q_flat"))
     ctx.param("wkv_a", (d, m.kv_lora_rank + m.qk_rope_dim), ("embed", "lora"))
     ctx.param("kv_norm/scale", (m.kv_lora_rank,), (None,), init="zeros")
     ctx.param("wkv_b", (m.kv_lora_rank, h * (m.qk_nope_dim + m.v_head_dim)),
@@ -36,11 +40,26 @@ def _project_q(cfg, p, x, positions, pre):
     b, t, _ = x.shape
     h = cfg.n_heads
     qd = m.qk_nope_dim + m.qk_rope_dim
-    ql = rms_norm(x @ p[f"{pre}wq_a"].astype(x.dtype), p[f"{pre}q_norm/scale"])
-    q = (ql @ p[f"{pre}wq_b"].astype(x.dtype)).reshape(b, t, h, qd)
+    if m.q_lora_rank:
+        ql = rms_norm(x @ p[f"{pre}wq_a"].astype(x.dtype),
+                      p[f"{pre}q_norm/scale"])
+        q = ql @ p[f"{pre}wq_b"].astype(x.dtype)
+    else:
+        q = x @ p[f"{pre}wq"].astype(x.dtype)
+    q = q.reshape(b, t, h, qd)
     q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
+
+
+def _softmax_scale(cfg):
+    """1/sqrt(q head dim), times YaRN's mscale(mscale_all_dim) squared
+    where the rope is scaled (DeepSeek-V2's ``softmax_scale``)."""
+    m, s = cfg.mla, cfg.rope_scaling
+    scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    if s is not None and s.mscale_all_dim:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
 
 
 def _project_kv_latent(cfg, p, x, positions, pre):
@@ -48,14 +67,19 @@ def _project_kv_latent(cfg, p, x, positions, pre):
     kv = x @ p[f"{pre}wkv_a"].astype(x.dtype)
     c_kv = rms_norm(kv[..., :m.kv_lora_rank], p[f"{pre}kv_norm/scale"])
     k_rope = kv[..., m.kv_lora_rank:]           # (b, t, rope_dim), head-shared
-    k_rope = apply_rope(k_rope[..., None, :], positions,
-                        cfg.rope_theta)[..., 0, :]
+    k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)[..., 0, :]
     return c_kv, k_rope
 
 
 def mla_prefill(cfg, p, x, positions, prefix: str = "", cache=None,
                 write_pos=0):
     """Expanded-form causal MLA over the full sequence."""
+    with jax.named_scope(MLA):
+        return _mla_prefill(cfg, p, x, positions, prefix, cache, write_pos)
+
+
+def _mla_prefill(cfg, p, x, positions, prefix, cache, write_pos):
     pre = prefix + "/" if prefix else ""
     m = cfg.mla
     b, t, _ = x.shape
@@ -66,7 +90,7 @@ def mla_prefill(cfg, p, x, positions, prefix: str = "", cache=None,
         m.kv_lora_rank, h, m.qk_nope_dim + m.v_head_dim)
     k_nope = jnp.einsum("btk,khn->bthn", c_kv, wkv_b[..., :m.qk_nope_dim])
     v = jnp.einsum("btk,khv->bthv", c_kv, wkv_b[..., m.qk_nope_dim:])
-    scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _softmax_scale(cfg)
 
     cq = 1024 if (t % 1024 == 0 and t > 1024) else t
     if cq == t:
@@ -153,7 +177,7 @@ def mla_decode(cfg, p, x, cur_pos, cache, prefix: str = ""):
     w_uv = wkv_b[..., m.qk_nope_dim:]            # (kv_lora, h, v)
     # absorb W_UK into the query: q_c (b,1,h,kv_lora)
     q_c = jnp.einsum("bthn,khn->bthk", q_nope, w_uk)
-    scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _softmax_scale(cfg)
     s = cache["c_kv"].shape[1]
     kv_pos = jnp.arange(s)
     mask = kv_pos <= cur_pos                     # (s,)

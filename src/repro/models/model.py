@@ -81,8 +81,8 @@ def _cross_kv(cfg, p, enc_out):
 
 def apply_layer_prefill(cfg, spec, p, x, positions, cache=None,
                         write_pos=0, enc_out=None, constrain: Constrain = None):
-    """Returns (x, new_cache, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Returns (x, new_cache, aux): ``aux`` as ``moe.no_aux`` gives it."""
+    aux = moe_mod.no_aux()
     new_cache: Dict[str, jax.Array] = {}
     h = _norm(cfg, p, "ln_seq", x)
     if spec.kind == KIND_MAMBA:
@@ -117,10 +117,9 @@ def apply_layer_prefill(cfg, spec, p, x, positions, cache=None,
     if spec.mlp == "dense":
         x = x + apply_mlp(p, _norm(cfg, p, "ln_mlp", x), prefix="mlp")
     elif spec.mlp == "moe":
-        y, a = moe_mod.apply_moe(cfg, p, _norm(cfg, p, "ln_mlp", x),
-                                 prefix="moe")
+        y, aux = moe_mod.apply_moe(cfg, p, _norm(cfg, p, "ln_mlp", x),
+                                   prefix="moe")
         x = x + y
-        aux = aux + a
     if constrain:
         x = constrain(x)
     return x, new_cache, aux
@@ -271,12 +270,17 @@ def _encode(cfg, params, frontend, constrain: Constrain = None):
     return _norm(cfg, subtree(params, "enc"), "final_norm", x)
 
 
+def _add(a, b):
+    return jax.tree_util.tree_map(jnp.add, a, b)
+
+
 def forward(cfg: ModelConfig, params, batch, *, cache=None, write_pos=0,
             remat: bool = False, constrain: Constrain = None):
     """Full-sequence forward (train / prefill).
 
     batch: {'tokens': (b, t_text)} plus 'frontend': (b, t_f, d) for vlm/audio.
-    Returns (logits over text positions, new_cache, aux_loss).
+    Returns (logits over text positions, new_cache, aux), ``aux`` the MoE
+    layers' extras summed (``moe.no_aux``).
     """
     tokens = batch["tokens"]
     x = embed_tokens(cfg, params, tokens)
@@ -290,16 +294,20 @@ def forward(cfg: ModelConfig, params, batch, *, cache=None, write_pos=0,
         x = constrain(x)
     b, t, _ = x.shape
     positions = jnp.arange(t)
-    aux = jnp.zeros((), jnp.float32)
+    aux = moe_mod.no_aux()
     new_cache: Dict[str, jax.Array] = {}
 
     for i, spec in enumerate(cfg.prefix):
         lc = subtree(cache, f"pre/{i}") if cache is not None else None
-        x, c, a = apply_layer_prefill(cfg, spec, subtree(params, f"pre/{i}"),
-                                      x, positions, cache=lc,
-                                      write_pos=write_pos, enc_out=enc_out,
-                                      constrain=constrain)
-        aux += a
+
+        def layer(p, x, lc, spec=spec):
+            return apply_layer_prefill(cfg, spec, p, x, positions, cache=lc,
+                                       write_pos=write_pos, enc_out=enc_out,
+                                       constrain=constrain)
+
+        layer = jax.checkpoint(layer) if remat else layer
+        x, c, a = layer(subtree(params, f"pre/{i}"), x, lc)
+        aux = _add(aux, a)
         for k, v in c.items():
             new_cache[f"pre/{i}/{k}"] = v
 
@@ -320,7 +328,7 @@ def forward(cfg: ModelConfig, params, batch, *, cache=None, write_pos=0,
                                           cache=lc, write_pos=write_pos,
                                           enc_out=enc_out,
                                           constrain=constrain)
-            aux += a
+            aux = _add(aux, a)
             if c:
                 outs[j] = c
         return (x, aux), outs
@@ -381,7 +389,9 @@ def decode_step(cfg: ModelConfig, params, token, cur_pos, cache):
 
 def lm_loss(cfg: ModelConfig, params, batch, *, remat: bool = False,
             constrain: Constrain = None):
-    """Next-token cross-entropy (+ MoE aux). Returns (loss, metrics)."""
+    """Next-token cross-entropy (+ MoE balance loss). Returns (loss,
+    metrics); the metrics count the MoE layers' routed, kept and buffer
+    rows."""
     logits, _, aux = forward(cfg, params, batch, remat=remat,
                              constrain=constrain)
     tokens = batch["tokens"]
@@ -391,5 +401,8 @@ def lm_loss(cfg: ModelConfig, params, batch, *, remat: bool = False,
     tgt = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
     mask = (targets >= 0).astype(jnp.float32)
     ce = jnp.sum((lse - tgt) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    loss = ce + aux
-    return loss, {"ce": ce, "aux": aux}
+    loss = ce + aux["balance"]
+    return loss, {"ce": ce, "aux": aux["balance"],
+                  "moe_routed_rows": aux["routed_rows"],
+                  "moe_kept_rows": aux["kept_rows"],
+                  "moe_buffer_rows": aux["buffer_rows"]}

@@ -16,6 +16,10 @@ EXCHANGE = "fl.exchange"           # encode, decode, rotations, averaging
 NOISE = "fl.exchange.noise"        # the exchange's sign and dither draws
 POPULATION = "fl.population"       # the population store's row traffic
 SCOPES = (LOCAL_STEPS, EXCHANGE, NOISE, POPULATION)
+# the model's blocks inside the local steps (forward and backward); not in
+# SCOPES, whose readers pin its four layers
+MLA = "fl.local_steps.mla"         # latent attention
+MOE = "fl.local_steps.moe"         # router, dispatch, experts, combine
 
 # host spans
 ROUND = "fl.round"                 # one eager round's dispatch

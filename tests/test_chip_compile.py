@@ -252,3 +252,69 @@ def test_round_kernels_sit_in_the_exchange_scope(one_chip):
     assert names and len(names) == text.count(
         'custom_call_target="tpu_custom_call"')
     assert all(f"/{EXCHANGE}/" in n for n in names), names
+
+
+def test_deepseek_lite_cell_round_fits_a_v5e(one_chip):
+    """The `dsv2lite-ep8-train` round (DeepSeek-V2-Lite's widths, 1 dense
+    + 4 MoE layers holding 8 of 64 experts, 4 x 2048 tokens a step, fp32
+    server and client) compiled for one v5e chip: it leaves >= 2 GiB of
+    the chip's 16 GiB, the depth rule of the benchmark's configurations.
+    Its Mosaic kernels are the exchange's and the held experts' grouped
+    matmuls (``ragged_dot`` lowers to them), so a metric that counts every
+    ``tpu_custom_call`` as exchange time cannot read this cell."""
+    import json
+    import re
+    import sys
+    from pathlib import Path
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.fed import make_algorithm
+    from repro.utils.spans import EXCHANGE, MOE
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from bench import harness, traffic
+    from bench.systems import spmd_lm, spmd_lm_moe
+
+    device, = one_chip.device_set
+    mesh = Mesh(np.array([device]).reshape(1, 1), ("data", "model"))
+    spec = harness.load_spec()
+    wl, entry = harness.resolve(spec, "dsv2lite-ep8-train")
+    cfg = json.loads((root / entry["file"]).read_text())
+    tr = traffic.load(wl["traffic"])
+    model = harness.load_module(root / "bench" / "configs" /
+                                f"{entry['name']}.py", "dsv2lite_shapes")
+    template = jax.eval_shape(lambda: model.weights(cfg,
+                                                    jax.random.PRNGKey(0)))
+    seq = tr["inputs"]["seq"]
+    alg = make_algorithm("spmd", spmd_lm.fed_config(cfg, tr), loss_fn=None,
+                         template=template, batch_fn=None,
+                         cfg=spmd_lm_moe.model_config(cfg), mesh=mesh,
+                         batch=tr["batch"], seq=seq, remat=cfg["fed"]["remat"])
+    repl = NamedSharding(mesh, PartitionSpec())
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+        jax.eval_shape(alg.init, template))
+    data = {"tokens": jax.ShapeDtypeStruct((1, tr["inputs"]["pool"], seq),
+                                           jnp.int32, sharding=repl)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
+    compiled = type(alg)._round.lower(alg, state, data, key).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    headroom = 16 * 2**30 - used
+    print(f"dsv2lite-ep8-train round: {used / 1e9:.3f} GB, headroom "
+          f"{headroom / 2**30:.3f} GiB")
+    assert headroom >= 2 * 2**30
+    text = compiled.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]*)"', text)
+    assert len(names) == text.count('custom_call_target="tpu_custom_call"')
+    # the compiler names the grouped matmuls' kernels itself, with no
+    # scope: a trace reads them as unscoped, by their own names
+    experts = [n for n in names if f"/{EXCHANGE}/" not in n]
+    assert experts and all(n.startswith("ragged-dot") for n in experts)
+    assert f"/{MOE}/" in text

@@ -38,6 +38,7 @@ def test_full_config_matches_assignment(arch):
         "llama4-scout-17b-a16e": (48, 5120, 40, 8, 8192, 202048),
         "gemma2-2b": (26, 2304, 8, 4, 9216, 256000),
         "deepseek-v2-236b": (60, 5120, 128, 128, 12288, 102400),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
         "mamba2-370m": (48, 1024, 32, 0, 0, 50280),
         "llava-next-34b": (60, 7168, 56, 8, 20480, 64000),
         "seamless-m4t-medium": (12, 1024, 16, 16, 4096, 256206),
