@@ -22,14 +22,19 @@ and any external consumers are unaffected by the promotion.
 """
 from __future__ import annotations
 
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from repro.analysis.jaxpr import Violation, collective_bytes, op_report
+from repro.kernels import exchange
 
 # counter names the rotation audit uses
 ROT_FWD = "rotation_fwd"
 ROT_INV = "rotation_inv"
+# counter names of the exchange kernels' grids: steps and Hadamard blocks
+EXCHANGE_GRID_STEPS = exchange.GRID_STEPS
+EXCHANGE_BLOCKS = exchange.GRID_BLOCKS
 
 
 @dataclass
@@ -81,6 +86,21 @@ class OpBudget:
                     "op-budget", where,
                     f"counter {name!r}: {got} != budgeted {want}"))
         return out
+
+
+@contextmanager
+def exchange_grid_counts(budget: Optional[OpBudget] = None):
+    """Count the exchange kernels' grids while the block traces: each call
+    that reaches a wrapper of :mod:`repro.kernels.exchange` adds its grid
+    steps and Hadamard blocks, so blocks per step read off as
+    ``EXCHANGE_BLOCKS / EXCHANGE_GRID_STEPS``. A wrapper traced under
+    ``vmap`` counts its unbatched call once."""
+    budget = OpBudget() if budget is None else budget
+    exchange.GRID_SINKS.append(budget)
+    try:
+        yield budget
+    finally:
+        exchange.GRID_SINKS.remove(budget)
 
 
 def check_collective_bytes(closed, where: str,

@@ -38,11 +38,17 @@ bit-for-bit the historical unpacked path. :func:`pack_codes` /
 :func:`unpack_codes` are the jnp reference implementations of the same
 layout (used by the ``jnp`` backend and the per-message codec API).
 
-All kernels run over a ``(m, nb)`` grid — ``m`` messages by ``nb`` Hadamard
-blocks — with one (r, c) block per step; the two small Hadamard factors hit
-the MXU directly. Batched operands broadcast along ``m`` through the block
-index maps (no HBM materialization of the broadcast). Per-message scales
-``gamma`` ride whole in SMEM as an (m, 1) column and each grid step reads
+All kernels run over a ``(m, nb // g)`` grid — ``m`` messages by steps of
+``g`` Hadamard blocks. A grid step has a fixed cost (~0.35 us on a v5e)
+that a 128 x 128 block's own work (~0.3-0.5 us) does not hide, so each
+step takes the most blocks that divide ``nb`` and fit the scoped VMEM
+(:func:`_blocks_per_step`, from the shapes and dtypes alone). A step
+applies the left Hadamard factor block by block and the right one to all
+``g r`` rows at once, so every block's arithmetic is that of a one-block
+step, bit for bit; the two small Hadamard factors stay resident in VMEM.
+Batched operands broadcast along ``m`` through the block index maps (no
+HBM materialization of the broadcast). Per-message scales ``gamma`` ride
+whole in SMEM as an (m, 1) column and each grid step reads
 its message's scalar at ``program_id(0)``: a per-message VMEM row would
 need a (1, 128) block over an (m, 128) array, which Mosaic refuses for
 m > 1 (the last two block dims must be multiples of (8, 128) or the full
@@ -70,6 +76,7 @@ tests run the same bodies through the Pallas interpreter.
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -119,22 +126,24 @@ def _check_pack(pack: int, bits: int, r: int):
 
 
 def _pack_block(q, pack: int, bits: int):
-    """(r, c) integral float codes -> (r//pack, c) uint8, packed along
-    sublanes. Shifts and sums run in int32: Mosaic reduces no unsigned
-    integers, and a packed byte is < 256."""
-    r, c = q.shape
-    qi = q.astype(jnp.int32).reshape(r // pack, pack, c)
-    shifts = (jnp.arange(pack, dtype=jnp.int32) * bits)[None, :, None]
-    return jnp.sum(qi << shifts, axis=1).astype(jnp.uint8)
+    """(g, r, c) integral float codes -> (g, r//pack, c) uint8, packed
+    along each block's sublanes. Shifts and sums run in int32: Mosaic
+    reduces no unsigned integers, and a packed byte is < 256."""
+    g, r, c = q.shape
+    qi = q.astype(jnp.int32).reshape(g, r // pack, pack, c)
+    shifts = (jnp.arange(pack, dtype=jnp.int32) * bits
+              ).reshape(1, 1, pack, 1)
+    return jnp.sum(qi << shifts, axis=2).astype(jnp.uint8)
 
 
 def _unpack_block(p, pack: int, bits: int):
-    """(r//pack, c) packed uint8 -> (r, c) uint32 codes."""
-    rp, c = p.shape
-    pi = p.astype(jnp.uint32)[:, None, :]
-    shifts = (jnp.arange(pack, dtype=jnp.uint32) * bits)[None, :, None]
+    """(g, r//pack, c) packed uint8 -> (g, r, c) uint32 codes."""
+    g, rp, c = p.shape
+    pi = p.astype(jnp.uint32)[:, :, None, :]
+    shifts = (jnp.arange(pack, dtype=jnp.uint32) * bits
+              ).reshape(1, 1, pack, 1)
     mask = jnp.uint32((1 << bits) - 1)
-    return ((pi >> shifts) & mask).reshape(rp * pack, c)
+    return ((pi >> shifts) & mask).reshape(g, rp * pack, c)
 
 
 def pack_codes(codes2: jnp.ndarray, *, bits: int,
@@ -168,32 +177,126 @@ def unpack_codes(packed2: jnp.ndarray, *, bits: int,
     return ((x >> shifts) & mask).reshape(m, d_pad)
 
 
-def _row_spec(m: int, r: int, c: int):
-    """BlockSpec for a (m_or_1, nb, r, c) operand broadcast along the grid's
-    message axis when its leading dim is 1."""
-    if m == 1:
-        return pl.BlockSpec((1, 1, r, c), lambda i, j: (0, j, 0, 0))
-    return pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0))
+# ---------------------------------------------------------------------------
+# the grouped grid
+# ---------------------------------------------------------------------------
+
+# A TPU core's default scoped VMEM (16 MiB on a v5e): a grid step's
+# double-buffered operand and output blocks and its f32 intermediates must
+# fit in it
+_SCOPED_VMEM = 16 << 20
+
+# trace-time sinks (``OpBudget``s) of the grid counters; see
+# ``repro.analysis.opbudget.exchange_grid_counts``
+GRID_SINKS: list = []
+GRID_STEPS = "exchange_grid_steps"
+GRID_BLOCKS = "exchange_blocks"
 
 
-def _blk(x2: jnp.ndarray, nb: int, r: int, c: int):
-    return x2.reshape(x2.shape[0], nb, r, c)
+class _Grid(NamedTuple):
+    """One call's grid: ``m`` messages by ``nb // g`` steps of ``g``
+    Hadamard blocks of (r, c). Hashable, so it rides as a static argument
+    of the jitted launch."""
+    m: int
+    nb: int
+    r: int
+    c: int
+    g: int
+
+    @property
+    def scale(self) -> float:
+        return 1.0 / np.sqrt(self.r * self.c)
+
+    def steps(self):
+        return (self.m, self.nb // self.g)
+
+    def spec(self, rows: int, broadcast: bool = False):
+        """BlockSpec of a (m_or_1, nb, rows, c) message operand, read
+        along the message axis or broadcast over it."""
+        if broadcast:
+            return pl.BlockSpec((1, self.g, rows, self.c),
+                                lambda i, j: (0, j, 0, 0))
+        return pl.BlockSpec((1, self.g, rows, self.c),
+                            lambda i, j: (i, j, 0, 0))
+
+    def signs_spec(self):
+        return pl.BlockSpec((self.g, self.r, self.c), lambda i, j: (j, 0, 0))
+
+    def factor_specs(self):
+        return [pl.BlockSpec((self.r, self.r), lambda i, j: (0, 0)),
+                pl.BlockSpec((self.c, self.c), lambda i, j: (0, 0))]
+
+    def blocks(self, x2, dtype, rows: Optional[int] = None):
+        return x2.astype(dtype).reshape(x2.shape[0], self.nb,
+                                        rows or self.r, self.c)
+
+
+def _blocks_per_step(nb: int, r: int, c: int, streamed: float,
+                     temps: int) -> int:
+    """Hadamard blocks a grid step takes: the largest power of two that
+    divides ``nb`` and whose step fits the scoped VMEM.
+
+    ``streamed`` is the bytes per element of the step's operand and output
+    blocks, each double-buffered by the pipeline; ``temps`` counts the
+    step's block-sized f32 intermediates. Each kernel's ``temps`` is what
+    the v5e compiler reports beyond the buffers, in f32 blocks at r = c =
+    128, rounded up: rotate and encode 3.3, quantize 2 (packed 3.8), snap
+    3.5 (packed 5), decode 4.4 (packed 7.9). The Hadamard factors stay
+    resident. A step of g blocks collapses them to (g r, c) rows for the
+    right factor, free only where r is a multiple of the 8 sublanes, so
+    other r keep one block a step.
+    """
+    if r % 8:
+        return 1
+    fixed = 2 * 4 * (r * r + c * c)
+    per_block = r * c * (2 * streamed + 4 * temps)
+    g = 1
+    while nb % (2 * g) == 0 and fixed + 2 * g * per_block <= _SCOPED_VMEM:
+        g *= 2
+    return g
+
+
+def _grid(m: int, d_pad: int, block: int, streamed: float,
+          temps: int) -> _Grid:
+    """Plan a call's grid and add it to the active grid counters. Runs
+    outside the jitted launch, whose trace cache would skip a count made
+    inside it."""
+    _, _, r, c, nb = block_geometry(d_pad, block)
+    grid = _Grid(m, nb, r, c, _blocks_per_step(nb, r, c, streamed, temps))
+    for sink in GRID_SINKS:
+        sink.add(GRID_STEPS, m * nb // grid.g)
+        sink.add(GRID_BLOCKS, m * nb)
+    return grid
+
+
+def _code_bytes(pack: int) -> float:
+    """Bytes a code takes in its block: uint32, or ``pack`` to a byte."""
+    return 4.0 if pack == 1 else 1.0 / pack
 
 
 # ---------------------------------------------------------------------------
-# kernel bodies
+# kernel bodies: refs hold (1, g, r, c) message blocks, (g, r, c) signs
 # ---------------------------------------------------------------------------
+
+def _rot(hr, hc, x, scale: float):
+    """H_r @ X @ H_c * scale for each (r, c) block X of x (g, r, c): the
+    left factor a block at a time, the right one over all g r rows at
+    once. Each output element sums the same products in the same order
+    as a one-block step, so g changes no bit."""
+    g, r, c = x.shape
+    y = jnp.stack([mm_f32(hr, x[k]) for k in range(g)])
+    return (mm_f32(y.reshape(g * r, c), hc) * scale).reshape(g, r, c)
+
 
 def _rotate_kernel(x_ref, s_ref, hr_ref, hc_ref, o_ref, *, scale: float,
                    inverse: bool):
-    x = x_ref[0, 0].astype(jnp.float32)
+    x = x_ref[0].astype(jnp.float32)
     if not inverse:
-        x = x * s_ref[0]
-    y = mm_f32(hr_ref[...], x)
-    y = mm_f32(y, hc_ref[...]) * scale
+        x = x * s_ref[...]
+    y = _rot(hr_ref[...], hc_ref[...], x, scale)
     if inverse:
-        y = y * s_ref[0]
-    o_ref[0, 0] = y
+        y = y * s_ref[...]
+    o_ref[0] = y
 
 
 def _bits_of(levels: int) -> int:
@@ -216,62 +319,63 @@ def _from_codes(c):
     return c.astype(jnp.int32).astype(jnp.float32)
 
 
+def _store_codes(c_ref, q, levels: int, pack: int):
+    c_ref[0] = (_to_codes(q) if pack == 1
+                else _pack_block(q, pack, _bits_of(levels)))
+
+
+def _load_codes(c_ref, levels: int, pack: int):
+    c = c_ref[0]
+    if pack > 1:
+        c = _unpack_block(c, pack, _bits_of(levels))
+    return _from_codes(c)
+
+
 def _encode_kernel(x_ref, s_ref, u_ref, hr_ref, hc_ref, g_ref, l_ref, c_ref,
                    y_ref, *, scale: float, levels: int, want_rotated: bool,
                    pack: int = 1):
-    x = x_ref[0, 0].astype(jnp.float32) * s_ref[0]
-    y = mm_f32(hr_ref[...], x)
-    y = mm_f32(y, hc_ref[...]) * scale
+    x = x_ref[0].astype(jnp.float32) * s_ref[...]
+    y = _rot(hr_ref[...], hc_ref[...], x, scale)
     g = g_ref[pl.program_id(0), 0]
-    q = jnp.floor(y / g + u_ref[0, 0])
-    q = jnp.mod(q, _modulus(l_ref, levels))
-    c_ref[0, 0] = (_to_codes(q) if pack == 1
-                   else _pack_block(q, pack, _bits_of(levels)))
+    q = jnp.floor(y / g + u_ref[0])
+    _store_codes(c_ref, jnp.mod(q, _modulus(l_ref, levels)), levels, pack)
     if want_rotated:
-        y_ref[0, 0] = y
+        y_ref[0] = y
 
 
 def _quantize_kernel(y_ref, u_ref, g_ref, l_ref, c_ref, *, levels: int,
                      pack: int = 1):
     g = g_ref[pl.program_id(0), 0]
-    q = jnp.floor(y_ref[0, 0].astype(jnp.float32) / g + u_ref[0, 0])
-    q = jnp.mod(q, _modulus(l_ref, levels))
-    c_ref[0, 0] = (_to_codes(q) if pack == 1
-                   else _pack_block(q, pack, _bits_of(levels)))
+    q = jnp.floor(y_ref[0].astype(jnp.float32) / g + u_ref[0])
+    _store_codes(c_ref, jnp.mod(q, _modulus(l_ref, levels)), levels, pack)
+
+
+def _snap(c, w, g, lv):
+    """The representative of codes c nearest w / g, times g."""
+    return (c + lv * jnp.round((w / g - c) / lv)) * g
 
 
 def _snap_kernel(c_ref, w_ref, g_ref, l_ref, o_ref, *, levels: int,
                  pack: int = 1):
     g = g_ref[pl.program_id(0), 0]
-    c = c_ref[0, 0]
-    if pack > 1:
-        c = _unpack_block(c, pack, _bits_of(levels))
-    c = _from_codes(c)
-    lv = _modulus(l_ref, levels)
-    q = c + lv * jnp.round((w_ref[0, 0] / g - c) / lv)
-    o_ref[0, 0] = q * g
+    o_ref[0] = _snap(_load_codes(c_ref, levels, pack), w_ref[0], g,
+                     _modulus(l_ref, levels))
 
 
 def _decode_kernel(c_ref, ref_ref, s_ref, hr_ref, hc_ref, g_ref, l_ref,
                    o_ref, *, scale: float, levels: int, pack: int = 1):
-    s = s_ref[0]
-    w = ref_ref[0, 0].astype(jnp.float32) * s
-    w = mm_f32(hr_ref[...], w)
-    w = mm_f32(w, hc_ref[...]) * scale
+    s = s_ref[...]
+    hr, hc = hr_ref[...], hc_ref[...]
+    w = _rot(hr, hc, ref_ref[0].astype(jnp.float32) * s, scale)
     g = g_ref[pl.program_id(0), 0]
-    c = c_ref[0, 0]
-    if pack > 1:
-        c = _unpack_block(c, pack, _bits_of(levels))
-    c = _from_codes(c)
-    lv = _modulus(l_ref, levels)
-    q = c + lv * jnp.round((w / g - c) / lv)
-    x = mm_f32(hr_ref[...], q * g)
-    x = mm_f32(x, hc_ref[...]) * scale
-    o_ref[0, 0] = x * s
+    q = _snap(_load_codes(c_ref, levels, pack), w, g,
+              _modulus(l_ref, levels))
+    o_ref[0] = _rot(hr, hc, q, scale) * s
 
 
 # ---------------------------------------------------------------------------
-# jit'd wrappers — all take (m, d_pad) message batches + (d_pad,) signs
+# wrappers — all take (m, d_pad) message batches + (d_pad,) signs. Each
+# plans its grid in Python, then runs a jitted launch with the grid static.
 # ---------------------------------------------------------------------------
 
 def _levels_operand(levels2, m: int):
@@ -281,32 +385,36 @@ def _levels_operand(levels2, m: int):
         return [], []
     return [_SMEM_SPEC], [_scalars(levels2, m)]
 
-@partial(jax.jit, static_argnames=("block", "inverse", "interpret"))
+
+def _code_dtype(pack: int):
+    return jnp.uint8 if pack > 1 else jnp.uint32
+
+
 def fused_rotate(x2: jnp.ndarray, signs: jnp.ndarray, *,
                  block: int = DEFAULT_BLOCK, inverse: bool = False,
                  interpret: bool) -> jnp.ndarray:
     """Batched randomized-Hadamard rotation: (m, d_pad) -> (m, d_pad)."""
-    m, d_pad = x2.shape
-    b, _, r, c, nb = block_geometry(d_pad, block)
+    grid = _grid(x2.shape[0], x2.shape[1], block, streamed=12, temps=4)
+    return _rotate_launch(x2, signs, grid=grid, inverse=inverse,
+                          interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("grid", "inverse", "interpret"))
+def _rotate_launch(x2, signs, *, grid: _Grid, inverse: bool,
+                   interpret: bool):
+    m, nb, r, c, _ = grid
     hr, hc = _had(r, c)
     out = pl.pallas_call(
-        partial(_rotate_kernel, scale=1.0 / np.sqrt(b), inverse=inverse),
-        grid=(m, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, r, c), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((r, r), lambda i, j: (0, 0)),
-            pl.BlockSpec((c, c), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
+        partial(_rotate_kernel, scale=grid.scale, inverse=inverse),
+        grid=grid.steps(),
+        in_specs=[grid.spec(r), grid.signs_spec(), *grid.factor_specs()],
+        out_specs=grid.spec(r),
         out_shape=jax.ShapeDtypeStruct((m, nb, r, c), jnp.float32),
         interpret=interpret,
-    )(_blk(x2.astype(jnp.float32), nb, r, c), signs.reshape(nb, r, c), hr, hc)
-    return out.reshape(m, d_pad)
+    )(grid.blocks(x2, jnp.float32), signs.reshape(nb, r, c), hr, hc)
+    return out.reshape(x2.shape)
 
 
-@partial(jax.jit, static_argnames=("bits", "block", "want_rotated",
-                                   "interpret", "pack"))
 def fused_encode(x2: jnp.ndarray, signs: jnp.ndarray, u2: jnp.ndarray,
                  gammas: jnp.ndarray, *, bits: int = 8,
                  block: int = DEFAULT_BLOCK, want_rotated: bool = False,
@@ -322,18 +430,26 @@ def fused_encode(x2: jnp.ndarray, signs: jnp.ndarray, u2: jnp.ndarray,
     VMEM->HBM store per block instead of a second full rotation pass
     later).
     """
-    m, d_pad = x2.shape
-    b, _, r, c, nb = block_geometry(d_pad, block)
-    _check_pack(pack, bits, r)
+    streamed = 12 + _code_bytes(pack) + 4 * want_rotated
+    grid = _grid(x2.shape[0], x2.shape[1], block, streamed, temps=4)
+    _check_pack(pack, bits, grid.r)
+    return _encode_launch(x2, signs, u2, gammas, levels2, grid=grid,
+                          bits=bits, want_rotated=want_rotated,
+                          interpret=interpret, pack=pack)
+
+
+@partial(jax.jit, static_argnames=("grid", "bits", "want_rotated",
+                                   "interpret", "pack"))
+def _encode_launch(x2, signs, u2, gammas, levels2, *, grid: _Grid, bits: int,
+                   want_rotated: bool, interpret: bool, pack: int):
+    m, nb, r, c, _ = grid
     hr, hc = _had(r, c)
     rp = r // pack
-    code_dt = jnp.uint8 if pack > 1 else jnp.uint32
-    out_shape = [jax.ShapeDtypeStruct((m, nb, rp, c), code_dt)]
-    out_specs = [pl.BlockSpec((1, 1, rp, c), lambda i, j: (i, j, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((m, nb, rp, c), _code_dtype(pack))]
+    out_specs = [grid.spec(rp)]
     if want_rotated:
         out_shape.append(jax.ShapeDtypeStruct((m, nb, r, c), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, r, c),
-                                      lambda i, j: (i, j, 0, 0)))
+        out_specs.append(grid.spec(r))
     l_specs, l_ops = _levels_operand(levels2, m)
     has_levels = levels2 is not None
 
@@ -342,33 +458,25 @@ def fused_encode(x2: jnp.ndarray, signs: jnp.ndarray, u2: jnp.ndarray,
         outs = rest[1:] if has_levels else rest
         _encode_kernel(x_ref, s_ref, u_ref, hr_ref, hc_ref, g_ref, l_ref,
                        outs[0], outs[1] if want_rotated else None,
-                       scale=1.0 / np.sqrt(b), levels=1 << bits,
+                       scale=grid.scale, levels=1 << bits,
                        want_rotated=want_rotated, pack=pack)
 
     res = pl.pallas_call(
         body,
-        grid=(m, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, r, c), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((r, r), lambda i, j: (0, 0)),
-            pl.BlockSpec((c, c), lambda i, j: (0, 0)),
-            _SMEM_SPEC,
-        ] + l_specs,
+        grid=grid.steps(),
+        in_specs=[grid.spec(r), grid.signs_spec(), grid.spec(r),
+                  *grid.factor_specs(), _SMEM_SPEC] + l_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(_blk(x2.astype(jnp.float32), nb, r, c), signs.reshape(nb, r, c),
-      _blk(u2.astype(jnp.float32), nb, r, c), hr, hc, _scalars(gammas, m),
-      *l_ops)
-    codes = res[0].reshape(m, d_pad // pack)
+    )(grid.blocks(x2, jnp.float32), signs.reshape(nb, r, c),
+      grid.blocks(u2, jnp.float32), hr, hc, _scalars(gammas, m), *l_ops)
+    codes = res[0].reshape(m, nb * rp * c)
     if want_rotated:
-        return res[1].reshape(m, d_pad), codes
+        return res[1].reshape(m, nb * r * c), codes
     return codes
 
 
-@partial(jax.jit, static_argnames=("bits", "block", "interpret", "pack"))
 def quantize_codes(y2: jnp.ndarray, u2: jnp.ndarray, gammas: jnp.ndarray, *,
                    bits: int = 8, block: int = DEFAULT_BLOCK,
                    interpret: bool, pack: int = 1,
@@ -381,11 +489,18 @@ def quantize_codes(y2: jnp.ndarray, u2: jnp.ndarray, gammas: jnp.ndarray, *,
     cached rotated vector costs no rotation pass. Bit-identical to the
     quantize half of ``fused_encode`` (``pack`` included).
     """
-    m, d_pad = y2.shape
-    _, _, r, c, nb = block_geometry(d_pad, block)
-    _check_pack(pack, bits, r)
+    grid = _grid(y2.shape[0], y2.shape[1], block, 8 + _code_bytes(pack),
+                 temps=3 if pack == 1 else 5)
+    _check_pack(pack, bits, grid.r)
+    return _quantize_launch(y2, u2, gammas, levels2, grid=grid, bits=bits,
+                            interpret=interpret, pack=pack)
+
+
+@partial(jax.jit, static_argnames=("grid", "bits", "interpret", "pack"))
+def _quantize_launch(y2, u2, gammas, levels2, *, grid: _Grid, bits: int,
+                     interpret: bool, pack: int):
+    m, nb, r, c, _ = grid
     rp = r // pack
-    code_dt = jnp.uint8 if pack > 1 else jnp.uint32
     l_specs, l_ops = _levels_operand(levels2, m)
     has_levels = levels2 is not None
 
@@ -396,22 +511,16 @@ def quantize_codes(y2: jnp.ndarray, u2: jnp.ndarray, gammas: jnp.ndarray, *,
 
     out = pl.pallas_call(
         body,
-        grid=(m, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
-            _SMEM_SPEC,
-        ] + l_specs,
-        out_specs=pl.BlockSpec((1, 1, rp, c), lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, nb, rp, c), code_dt),
+        grid=grid.steps(),
+        in_specs=[grid.spec(r), grid.spec(r), _SMEM_SPEC] + l_specs,
+        out_specs=grid.spec(rp),
+        out_shape=jax.ShapeDtypeStruct((m, nb, rp, c), _code_dtype(pack)),
         interpret=interpret,
-    )(_blk(y2.astype(jnp.float32), nb, r, c),
-      _blk(u2.astype(jnp.float32), nb, r, c), _scalars(gammas, m),
-      *l_ops)
-    return out.reshape(m, d_pad // pack)
+    )(grid.blocks(y2, jnp.float32), grid.blocks(u2, jnp.float32),
+      _scalars(gammas, m), *l_ops)
+    return out.reshape(m, nb * rp * c)
 
 
-@partial(jax.jit, static_argnames=("bits", "block", "interpret", "pack"))
 def snap_codes(codes2: jnp.ndarray, wrot2: jnp.ndarray, gammas: jnp.ndarray,
                *, bits: int = 8, block: int = DEFAULT_BLOCK,
                interpret: bool, pack: int = 1,
@@ -424,14 +533,19 @@ def snap_codes(codes2: jnp.ndarray, wrot2: jnp.ndarray, gammas: jnp.ndarray,
     codes arrive sub-byte packed and are unpacked inline, inside the
     kernel.
     """
-    mc, d_padp = codes2.shape
-    d_pad = d_padp * pack
-    mw = wrot2.shape[0]
-    m = max(mc, mw)
-    _, _, r, c, nb = block_geometry(d_pad, block)
-    _check_pack(pack, bits, r)
+    m = max(codes2.shape[0], wrot2.shape[0])
+    grid = _grid(m, wrot2.shape[1], block, 8 + _code_bytes(pack),
+                 temps=4 if pack == 1 else 6)
+    _check_pack(pack, bits, grid.r)
+    return _snap_launch(codes2, wrot2, gammas, levels2, grid=grid, bits=bits,
+                        interpret=interpret, pack=pack)
+
+
+@partial(jax.jit, static_argnames=("grid", "bits", "interpret", "pack"))
+def _snap_launch(codes2, wrot2, gammas, levels2, *, grid: _Grid, bits: int,
+                 interpret: bool, pack: int):
+    m, nb, r, c, _ = grid
     rp = r // pack
-    code_dt = jnp.uint8 if pack > 1 else jnp.uint32
     l_specs, l_ops = _levels_operand(levels2, m)
     has_levels = levels2 is not None
 
@@ -442,22 +556,17 @@ def snap_codes(codes2: jnp.ndarray, wrot2: jnp.ndarray, gammas: jnp.ndarray,
 
     out = pl.pallas_call(
         body,
-        grid=(m, nb),
-        in_specs=[
-            _row_spec(mc, rp, c),
-            _row_spec(mw, r, c),
-            _SMEM_SPEC,
-        ] + l_specs,
-        out_specs=pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
+        grid=grid.steps(),
+        in_specs=[grid.spec(rp, codes2.shape[0] == 1),
+                  grid.spec(r, wrot2.shape[0] == 1), _SMEM_SPEC] + l_specs,
+        out_specs=grid.spec(r),
         out_shape=jax.ShapeDtypeStruct((m, nb, r, c), jnp.float32),
         interpret=interpret,
-    )(_blk(codes2.astype(code_dt), nb, rp, c),
-      _blk(wrot2.astype(jnp.float32), nb, r, c), _scalars(gammas, m),
-      *l_ops)
-    return out.reshape(m, d_pad)
+    )(grid.blocks(codes2, _code_dtype(pack), rp),
+      grid.blocks(wrot2, jnp.float32), _scalars(gammas, m), *l_ops)
+    return out.reshape(m, nb * r * c)
 
 
-@partial(jax.jit, static_argnames=("bits", "block", "interpret", "pack"))
 def fused_decode(codes2: jnp.ndarray, ref2: jnp.ndarray, signs: jnp.ndarray,
                  gammas: jnp.ndarray, *, bits: int = 8,
                  block: int = DEFAULT_BLOCK,
@@ -471,13 +580,19 @@ def fused_decode(codes2: jnp.ndarray, ref2: jnp.ndarray, signs: jnp.ndarray,
     (``pack > 1``) are unpacked inline. Returns (max(mc, mr), d_pad) fp32
     in original coordinates (caller unpads with [:, :d]).
     """
-    mc = codes2.shape[0]
-    mr, d_pad = ref2.shape
-    m = max(mc, mr)
-    b, _, r, c, nb = block_geometry(d_pad, block)
-    _check_pack(pack, bits, r)
+    m = max(codes2.shape[0], ref2.shape[0])
+    grid = _grid(m, ref2.shape[1], block, 12 + _code_bytes(pack),
+                 temps=5 if pack == 1 else 9)
+    _check_pack(pack, bits, grid.r)
+    return _decode_launch(codes2, ref2, signs, gammas, levels2, grid=grid,
+                          bits=bits, interpret=interpret, pack=pack)
+
+
+@partial(jax.jit, static_argnames=("grid", "bits", "interpret", "pack"))
+def _decode_launch(codes2, ref2, signs, gammas, levels2, *, grid: _Grid,
+                   bits: int, interpret: bool, pack: int):
+    m, nb, r, c, _ = grid
     rp = r // pack
-    code_dt = jnp.uint8 if pack > 1 else jnp.uint32
     hr, hc = _had(r, c)
     l_specs, l_ops = _levels_operand(levels2, m)
     has_levels = levels2 is not None
@@ -485,23 +600,18 @@ def fused_decode(codes2: jnp.ndarray, ref2: jnp.ndarray, signs: jnp.ndarray,
     def body(c_ref, ref_ref, s_ref, hr_ref, hc_ref, g_ref, *rest):
         _decode_kernel(c_ref, ref_ref, s_ref, hr_ref, hc_ref, g_ref,
                        rest[0] if has_levels else None, rest[-1],
-                       scale=1.0 / np.sqrt(b), levels=1 << bits, pack=pack)
+                       scale=grid.scale, levels=1 << bits, pack=pack)
 
     out = pl.pallas_call(
         body,
-        grid=(m, nb),
-        in_specs=[
-            _row_spec(mc, rp, c),
-            _row_spec(mr, r, c),
-            pl.BlockSpec((1, r, c), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((r, r), lambda i, j: (0, 0)),
-            pl.BlockSpec((c, c), lambda i, j: (0, 0)),
-            _SMEM_SPEC,
-        ] + l_specs,
-        out_specs=pl.BlockSpec((1, 1, r, c), lambda i, j: (i, j, 0, 0)),
+        grid=grid.steps(),
+        in_specs=[grid.spec(rp, codes2.shape[0] == 1),
+                  grid.spec(r, ref2.shape[0] == 1), grid.signs_spec(),
+                  *grid.factor_specs(), _SMEM_SPEC] + l_specs,
+        out_specs=grid.spec(r),
         out_shape=jax.ShapeDtypeStruct((m, nb, r, c), jnp.float32),
         interpret=interpret,
-    )(_blk(codes2.astype(code_dt), nb, rp, c),
-      _blk(ref2.astype(jnp.float32), nb, r, c), signs.reshape(nb, r, c),
-      hr, hc, _scalars(gammas, m), *l_ops)
-    return out.reshape(m, d_pad)
+    )(grid.blocks(codes2, _code_dtype(pack), rp),
+      grid.blocks(ref2, jnp.float32), signs.reshape(nb, r, c), hr, hc,
+      _scalars(gammas, m), *l_ops)
+    return out.reshape(m, nb * r * c)

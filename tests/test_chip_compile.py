@@ -12,12 +12,15 @@ imports every test file.
 """
 import os
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis.opbudget import (EXCHANGE_BLOCKS, EXCHANGE_GRID_STEPS,
+                                     exchange_grid_counts)
 from repro.compression.lattice import LatticeQuantizer
 from repro.compression.rotation import _signs, dither
 from repro.kernels.exchange import (fused_decode, fused_encode, fused_rotate,
@@ -162,6 +165,119 @@ def test_vmapped_leaf_exchange_compiles(one_chip):
                     _f32(one_chip, 3, 1, D), _f32(one_chip, 3, 1),
                     _f32(one_chip, 1, D))
     assert text.count("tpu_custom_call") >= 2
+
+
+# the exchange kernels at an LM leaf's width, (m, pack, levels row) as
+# in CASES; the MLP leaf of OLMo-1B's six layers: 6 x 2048 x 8192 weights,
+# 6144 Hadamard blocks of 128 x 128
+KERNELS = ["rotate", "encode", "encode-rotated", "quantize", "snap",
+           "decode"]
+WIRES = [(1, 1, False), (4, 2, True)]
+OLMO_NB = 6 * 2048 * 8192 // (128 * 128)
+
+
+def _kernel_call(kernel, m, pack, levels, nb, sharding=None):
+    """(fn, abstract operands) of one exchange kernel call at nb blocks."""
+    d = nb * 128 * 128
+    bits = 8 // pack
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    codes = jax.ShapeDtypeStruct((m, d // pack),
+                                 jnp.uint8 if pack > 1 else jnp.uint32,
+                                 sharding=sharding)
+    kw = dict(bits=bits, pack=pack, interpret=False)
+    lv = [f32(m)] if levels else []
+    if kernel == "rotate":
+        return (lambda x, s: fused_rotate(x, s, interpret=False),
+                [f32(m, d), f32(d)])
+    if kernel.startswith("encode"):
+        return (lambda x, s, u, g, *l: fused_encode(
+            x, s, u, g, want_rotated=kernel == "encode-rotated",
+            levels2=l[0] if l else None, **kw),
+            [f32(m, d), f32(d), f32(m, d), f32(m)] + lv)
+    if kernel == "quantize":
+        return (lambda y, u, g, *l: quantize_codes(
+            y, u, g, levels2=l[0] if l else None, **kw),
+            [f32(m, d), f32(m, d), f32(m)] + lv)
+    if kernel == "snap":
+        return (lambda c, w, g, *l: snap_codes(
+            c, w, g, levels2=l[0] if l else None, **kw),
+            [codes, f32(1, d), f32(m)] + lv)
+    return (lambda c, r, s, g, *l: fused_decode(
+        c, r, s, g, levels2=l[0] if l else None, **kw),
+        [codes, f32(1, d), f32(d), f32(m)] + lv)
+
+
+def _blocks_a_step(fn, args, run):
+    with exchange_grid_counts() as counts:
+        out = run(fn, *args)
+    steps = counts.get(EXCHANGE_GRID_STEPS)
+    assert steps > 0
+    return counts.get(EXCHANGE_BLOCKS) / steps, out
+
+
+@pytest.mark.parametrize("m,pack,levels", WIRES,
+                         ids=["m1-pack1", "m4-pack2-levels"])
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_grouped_kernels_compile_at_olmo_leaf(one_chip, kernel, m, pack,
+                                              levels):
+    """At an OLMo MLP leaf's 6144 blocks each kernel compiles with the
+    planned blocks a step, under the default scoped VMEM."""
+    fn, args = _kernel_call(kernel, m, pack, levels, OLMO_NB, one_chip)
+    g, text = _blocks_a_step(fn, args, _compile)
+    assert g > 1
+    assert "tpu_custom_call" in text
+
+
+# blocks a step the plan gives at r = c = 128: the LM leaves' nb (OLMo's
+# MLP and embedding stacks, its attention stack, DeepSeek's expert
+# stacks), the MLP's message, odd counts
+PLANNED = [(6144, 16), (6288, 16), (1536, 16), (5632, 16), (2, 2), (1, 1),
+           (393, 1)]
+
+
+@pytest.mark.parametrize("nb,g", PLANNED, ids=[f"nb{nb}" for nb, _ in PLANNED])
+@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("m,pack,levels", WIRES,
+                         ids=["m1-pack1", "m4-pack2-levels"])
+def test_blocks_per_step_plan(kernel, m, pack, levels, nb, g):
+    fn, args = _kernel_call(kernel, m, pack, levels, nb)
+    assert _blocks_a_step(fn, args, jax.eval_shape)[0] == g
+
+
+@pytest.mark.parametrize("pack,rotated", [(1, False), (1, True), (2, False),
+                                          (2, True)])
+def test_plan_never_takes_32_encode_blocks(pack, rotated):
+    """32 f32 encode blocks of 128 x 128 overflow the v5e's 16 MiB scoped
+    VMEM (the compiler reports 16.12M without the rotated output, 20.12M
+    with it), however many blocks the leaf has."""
+    d = (1 << 12) * 128 * 128
+    x = jax.ShapeDtypeStruct((1, d), jnp.float32)
+    enc = partial(fused_encode, bits=8 // pack, pack=pack,
+                  want_rotated=rotated, interpret=False)
+    g, _ = _blocks_a_step(enc, [x, jax.ShapeDtypeStruct((d,), jnp.float32),
+                                x, jax.ShapeDtypeStruct((1,), jnp.float32)],
+                          jax.eval_shape)
+    assert 1 < g < 32
+
+
+def test_vmapped_leaf_exchange_takes_groups_of_blocks():
+    """The round's leaf exchange, traced at an OLMo MLP leaf (abstract
+    shapes): each slot's encode and decode step over 8 or more blocks."""
+    d = OLMO_NB * 128 * 128
+    q = LatticeQuantizer(backend="pallas")
+    keys = jax.ShapeDtypeStruct((1, 2), jnp.uint32)
+    x = jax.ShapeDtypeStruct((1, d), jnp.float32)
+    hint = jax.ShapeDtypeStruct((1,), jnp.float32)
+
+    def exchange(k, x, h):
+        msgs = jax.vmap(q.encode)(k, x, h)
+        return jax.vmap(q.decode, in_axes=(0, 0, None))(k, msgs, x[0])
+
+    g, _ = _blocks_a_step(exchange, [keys, x, hint], jax.eval_shape)
+    assert g >= 8
 
 
 def _noise_ops(text, result):

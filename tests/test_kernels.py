@@ -5,8 +5,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.analysis.opbudget import (EXCHANGE_BLOCKS, EXCHANGE_GRID_STEPS,
+                                     exchange_grid_counts)
 from repro.kernels.exchange import (fused_decode, fused_encode, fused_rotate,
-                                    snap_codes)
+                                    pack_codes, quantize_codes, snap_codes)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.hadamard import hadamard_blocks
 from repro.kernels.lattice_quant import lattice_decode, lattice_encode
@@ -159,6 +161,85 @@ def test_fused_rotate_roundtrip_batched():
         np.asarray(y), atol=1e-4)
     back = fused_rotate(y, signs, inverse=True, interpret=True)[:, :d]
     np.testing.assert_allclose(np.asarray(back), np.asarray(x), atol=1e-4)
+
+
+# (nb, m, g): nb Hadamard blocks of 128 x 128, m messages, the blocks a
+# grid step is planned to take
+GROUPED = [(12, 3, 4), (5, 1, 1), (1, 3, 1), (2, 1, 2)]
+WIRES = [(1, False), (2, False), (1, True), (2, True)]   # (pack, levels row)
+
+
+def _grouped_operands(nb, m, pack):
+    b = 128 * 128
+    key = jax.random.PRNGKey(nb * 10 + m)
+    bits = 8 // pack
+    x = jax.random.normal(jax.random.fold_in(key, 1), (m, nb * b))
+    u = jax.random.uniform(jax.random.fold_in(key, 2), (m, nb * b))
+    codes = jax.random.randint(jax.random.fold_in(key, 3), (m, nb * b), 0,
+                               1 << bits).astype(jnp.uint32)
+    if pack > 1:
+        codes = pack_codes(codes, bits=bits)
+    gammas = 0.01 * (1.0 + jnp.arange(m, dtype=jnp.float32))
+    levels = (2.0 ** (bits - jnp.arange(m) % 3)).astype(jnp.float32)
+    return x, u, codes, gammas, levels, _signs(key, nb * b), bits
+
+
+def _run_kernel(kernel, x, u, codes, gammas, levels, signs, bits, pack):
+    """One call of ``kernel`` over whatever blocks its operands hold; the
+    (m, *) operands broadcast where their leading dim is 1."""
+    kw = dict(bits=bits, pack=pack, levels2=levels, interpret=True)
+    if kernel == "rotate":
+        return (fused_rotate(x, signs, interpret=True),
+                fused_rotate(x, signs, inverse=True, interpret=True))
+    if kernel == "encode":
+        return fused_encode(x, signs, u, gammas, want_rotated=True, **kw)
+    if kernel == "quantize":
+        return quantize_codes(x, u, gammas, **kw)
+    if kernel == "snap":     # one rotated reference for every message
+        return snap_codes(codes, x[:1], gammas, **kw)
+    # one message decoded against every reference
+    return fused_decode(codes[:1], x, signs, gammas[:1], bits=bits,
+                        pack=pack, interpret=True,
+                        levels2=None if levels is None else levels[:1])
+
+
+GROUPED_CASES = [
+    pytest.param(kernel, nb, m, g, pack, levels,
+                 id=f"{kernel}-nb{nb}-m{m}"
+                 + (f"-pack{pack}" + ("-levels" if levels else "")
+                    if kernel != "rotate" else ""))
+    for kernel in ("rotate", "encode", "quantize", "snap", "decode")
+    for nb, m, g in GROUPED
+    for pack, levels in (WIRES if kernel != "rotate" else WIRES[:1])]
+
+
+@pytest.mark.parametrize("kernel,nb,m,g,pack,levels", GROUPED_CASES)
+def test_grouped_grid_matches_one_block_steps(kernel, nb, m, g, pack,
+                                              levels):
+    """A grid step over g Hadamard blocks gives, bit for bit, what a step
+    of one block gives: the reference runs each block as its own call,
+    whose grid is one block a step."""
+    b = 128 * 128
+    x, u, codes, gammas, lv, signs, bits = _grouped_operands(nb, m, pack)
+    lv = lv if levels else None
+    with exchange_grid_counts() as counts:
+        got = _run_kernel(kernel, x, u, codes, gammas, lv, signs, bits, pack)
+    calls = 2 if kernel == "rotate" else 1
+    assert counts.get(EXCHANGE_BLOCKS) == calls * m * nb
+    assert counts.get(EXCHANGE_GRID_STEPS) == calls * m * nb // g
+
+    cb = b // pack
+    per_block = [_run_kernel(kernel, x[:, j * b:(j + 1) * b],
+                             u[:, j * b:(j + 1) * b],
+                             codes[:, j * cb:(j + 1) * cb], gammas, lv,
+                             signs[j * b:(j + 1) * b], bits, pack)
+                 for j in range(nb)]
+    want = jax.tree_util.tree_map(lambda *bl: jnp.concatenate(bl, axis=1),
+                                  *per_block)
+    for a, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == w.dtype and a.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
 
 
 @pytest.mark.parametrize(
