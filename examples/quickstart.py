@@ -28,7 +28,7 @@ from repro.models.mlp import init_mlp_classifier, mlp_loss
 
 def main():
     fed = FedConfig(n_clients=16, s=4, local_steps=5, lr=0.3, bits=8,
-                    swt=10.0, quantizer="lattice")
+                    swt=10.0)
     part, test = make_federated_classification(0, fed.n_clients, d=32,
                                                n_classes=10, iid=False)
     params0, _ = init_mlp_classifier(jax.random.PRNGKey(0), 32, 64, 10)

@@ -15,7 +15,7 @@ Every checker returns a list of :class:`Violation` — empty means clean.
 **Key-discipline policy.** The lattice exchange *intentionally* consumes one
 key twice with the SAME derivation — shared-randomness dithers: the decoder
 re-splits the encoder's key to reproduce its rotation/dither draws (see
-``LatticeQuantizer.decode``). Statically, identical (primitive, params,
+``LatticeCodec.decode``). Statically, identical (primitive, params,
 output-aval) consumption signatures are therefore the shared-randomness
 idiom, not a bug. What corrupts schedules is a key consumed by two
 *distinct* derivations — e.g. ``uniform(k, (8,))`` and ``normal(k, (4,))``

@@ -182,9 +182,10 @@ def flow_checks(closed, target, d: int, where: str) -> List:
     fed = target.fed
     up = getattr(target, "codec_up", None)
     dn = getattr(target, "codec_down", None)
-    up = up if up is not None else resolve_codec(None, fed, direction="up")
-    dn = dn if dn is not None else resolve_codec(None, fed,
-                                                 direction="down")
+    up = up if up is not None else resolve_codec(
+        None, fed, direction="up", default="lattice")
+    dn = dn if dn is not None else resolve_codec(
+        None, fed, direction="down", default="lattice")
     decl_up = (up.wire_declaration(d)
                if hasattr(up, "wire_declaration") else None)
     decl_dn = (dn.wire_declaration(d)
@@ -285,8 +286,8 @@ def _trace_exchange(codec_up_spec: str, codec_dn_spec: str,
     mesh = AbstractMesh((n, 2), ("data", "model"))
     fed = FedConfig(n_clients=n, s=n, bits=8, codec_up=codec_up_spec,
                     codec_down=codec_dn_spec)
-    up = resolve_codec(None, fed, direction="up")
-    dn = resolve_codec(None, fed, direction="down")
+    up = resolve_codec(None, fed, direction="up", default="lattice")
+    dn = resolve_codec(None, fed, direction="down", default="lattice")
     transport = make_transport(transport_name)
     srv_ps = {"w": P("model")} if model_sharded else {"w": P()}
     cl_ps = {"w": P("data", "model")} if model_sharded else {"w": P("data")}

@@ -1,12 +1,9 @@
-from repro.compression.lattice import (IdentityQuantizer, LatticeMsg,  # noqa: F401
-                                       LatticeQuantizer, QSGDQuantizer,
-                                       make_quantizer)
 from repro.compression.pipeline import (BACKENDS, Backend,  # noqa: F401
                                         ExchangePipeline, LatticeWire,
                                         get_backend, wrap_gamma)
 from repro.compression.codecs import (Codec, GroupedLatticeCodec,  # noqa: F401
                                       IdentityCodec, LatticeCodec,
-                                      ScalarCodec, TopKEFCodec,
+                                      LatticeMsg, ScalarCodec, TopKEFCodec,
                                       is_lattice_family, make_codec,
                                       register_codec, registered_codecs,
                                       resolve_codec)

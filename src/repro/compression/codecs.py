@@ -55,10 +55,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.provenance import wire_mark
-from repro.compression.lattice import (IdentityQuantizer, LatticeMsg,
-                                       LatticeQuantizer, QSGDQuantizer)
-from repro.compression.pipeline import LatticeWire
-from repro.compression.rotation import DEFAULT_BLOCK, pad_len
+from repro.compression.pipeline import (GAMMA_NORM_FLOOR, LatticeWire,
+                                        coord_bound, get_backend,
+                                        wire_container_dtype, wrap_gamma)
+from repro.compression.rotation import DEFAULT_BLOCK, _signs, dither, pad_len
 from repro.kernels.exchange import pack_codes, unpack_codes
 
 
@@ -74,6 +74,12 @@ class Codec(Protocol):
 
     def message_bits(self, d: int) -> int:
         ...
+
+
+class LatticeMsg(NamedTuple):
+    """One coded message: the payload and its transmitted scale."""
+    codes: jnp.ndarray     # (d_pad,) codes (lattice: uints in [0, 2^b))
+    gamma: jnp.ndarray     # () fp32 — transmitted scale (O(1) overhead)
 
 
 # ---------------------------------------------------------------------------
@@ -163,7 +169,7 @@ def init_client_states(codec, n: int, d: int):
 
 
 # ---------------------------------------------------------------------------
-# identity / scalar — thin codec views of the legacy quantizers
+# identity / scalar
 # ---------------------------------------------------------------------------
 
 @dataclass(frozen=True)
@@ -173,11 +179,10 @@ class IdentityCodec(CodecBase):
     bits: int = 32
 
     def encode(self, key, x, hint=None):
-        msg = IdentityQuantizer().encode(key, x, hint)
         return LatticeMsg(
-            codes=wire_mark(msg.codes, channel="msg", part="codes",
+            codes=wire_mark(x, channel="msg", part="codes",
                             codec=self.name, d=int(x.shape[-1])),
-            gamma=msg.gamma)
+            gamma=jnp.float32(1.0))
 
     def decode(self, key, msg, ref=None):
         return msg.codes
@@ -193,13 +198,15 @@ class IdentityCodec(CodecBase):
 @dataclass(frozen=True)
 class ScalarCodec(CodecBase):
     """FedPAQ-style norm-scaled stochastic rounding (arXiv:2106.07155's
-    quantizer; the paper's Figure-5 'direct quantization' baseline). Not
-    position-aware — ``ref`` is ignored and the error scales with ‖x‖."""
+    quantizer; QSGD [Alistarh et al.]; the paper's Figure-5 'direct
+    quantization' baseline). Not position-aware — ``ref`` is ignored and
+    the error scales with ‖x‖."""
     bits: int = 8
     name: str = "scalar"
 
-    def __post_init__(self):
-        object.__setattr__(self, "quant", QSGDQuantizer(bits=self.bits))
+    @property
+    def levels(self) -> int:
+        return (1 << (self.bits - 1)) - 1  # signed levels
 
     def _container(self):
         # signed storage of levels in [-(2^(b-1)-1), 2^(b-1)-1]
@@ -207,25 +214,26 @@ class ScalarCodec(CodecBase):
             jnp.int16 if self.bits <= 16 else jnp.int32)
 
     def encode(self, key, x, hint=None):
-        msg = self.quant.encode(key, x, hint)
-        # wire container honesty: the signed levels fit the b-bit int dtype;
-        # the legacy int32 working dtype is not what the wire would move
-        codes = wire_mark(msg.codes.astype(self._container()), channel="msg",
+        norm = jnp.linalg.norm(x) + 1e-12
+        y = jnp.abs(x) / norm * self.levels
+        u = dither(key, x.shape)
+        q = jnp.floor(y + u) * jnp.sign(x)
+        codes = wire_mark(q.astype(self._container()), channel="msg",
                           part="codes", codec=self.name, d=int(x.shape[-1]))
-        gamma = wire_mark(msg.gamma, channel="msg", part="gamma",
+        gamma = wire_mark(norm, channel="msg", part="gamma",
                           codec=self.name, d=int(x.shape[-1]))
         return LatticeMsg(codes=codes, gamma=gamma)
 
     def decode(self, key, msg, ref=None):
-        return self.quant.decode(key, msg, ref)
+        return msg.codes.astype(jnp.float32) * (msg.gamma / self.levels)
 
     def message_bits(self, d: int) -> int:
-        return self.quant.message_bits(d)
+        return d * self.bits + 32
 
     def wire_declaration(self, d: int) -> WireDecl:
+        container = jnp.dtype(self._container()).itemsize * 8
         return WireDecl(codec=self.name, parts=(
-            WirePart("codes", d, _storage_bits(self.bits), d * self.bits,
-                     "int", True),
+            WirePart("codes", d, container, d * self.bits, "int", True),
             WirePart("gamma", 1, 32, 32, "float", False)))
 
 
@@ -234,15 +242,70 @@ class ScalarCodec(CodecBase):
 # ---------------------------------------------------------------------------
 
 def _storage_bits(bits: int) -> int:
-    """Wire width of one unpacked lattice code: the uint dtype that holds
-    2^bits levels (what the interconnect actually moves — see
-    ``LatticeQuantizer.code_dtype``)."""
-    return 8 if bits <= 8 else (16 if bits <= 16 else 32)
+    """Wire width of one unpacked lattice code (see
+    ``pipeline.wire_container_dtype``)."""
+    return jnp.dtype(wire_container_dtype(LatticeWire(bits))).itemsize * 8
+
+
+def _lattice_encode(c, key, x, dist_hint) -> LatticeMsg:
+    """Enc(x) of one flat (d,) fp32 message at the lattice parameters of
+    codec ``c`` (``bits``, ``block``, ``safety``, ``backend``). ``key`` is
+    the interaction's shared rotation+rounding key (both ends derive it);
+    ``dist_hint`` an upper estimate of ‖x − ref‖₂, from which γ is set so
+    the wrap window 2^b·γ exceeds twice the max rotated difference
+    coordinate (subgaussian with scale dist/sqrt(d))."""
+    d = x.shape[0]
+    d_pad = pad_len(d, c.block)
+    gamma = wrap_gamma(jnp.asarray(dist_hint, jnp.float32), d, bits=c.bits,
+                       block=c.block, safety=c.safety)
+    # fp32 precision floor: the modulo decode needs y/γ (and w/γ) to
+    # keep sub-integer precision, so γ ≥ max|rot(x)|·2^-18. The max
+    # rotated coordinate is estimated pre-rotation from the (rotation-
+    # invariant) norm so γ is available before the fused rotate+quantize
+    # kernel runs. When the distance hint is tiny relative to the model
+    # norm the error bound degrades to the model's own fp32 resolution
+    # instead of silently mis-decoding.
+    gamma = jnp.maximum(gamma, coord_bound(jnp.linalg.norm(x), d_pad)
+                        * GAMMA_NORM_FLOOR)
+    krot, krnd = jax.random.split(key)
+    signs = _signs(krot, d_pad)
+    u = dither(krnd, (d_pad,))
+    x2 = jnp.pad(x.astype(jnp.float32), (0, d_pad - d))[None]
+    codes = get_backend(c.backend).encode(x2, signs, u[None], gamma[None],
+                                          bits=c.bits, block=c.block,
+                                          want_rotated=False)[0]
+    return LatticeMsg(
+        codes=codes.astype(wire_container_dtype(LatticeWire(c.bits))),
+        gamma=gamma)
+
+
+def _lattice_decode(c, key, msg: LatticeMsg, ref) -> jnp.ndarray:
+    """Dec(ref, msg): ref is the flat (d,) decoding key (the paper's y).
+    One fused pass: rotate the reference, snap each code to the
+    representative nearest the reference coordinate, inverse-rotate."""
+    d = ref.shape[0]
+    d_pad = pad_len(d, c.block)
+    krot, _ = jax.random.split(key)
+    signs = _signs(krot, d_pad)
+    ref2 = jnp.pad(ref.astype(jnp.float32), (0, d_pad - d))[None]
+    x = get_backend(c.backend).decode(msg.codes[None], ref2, signs,
+                                      jnp.reshape(msg.gamma, (1,)),
+                                      bits=c.bits, block=c.block)[0]
+    return x[:d]
 
 
 @dataclass(frozen=True)
 class LatticeCodec(CodecBase):
-    """Position-aware lattice quantizer as a codec.
+    """Position-aware lattice quantizer (Davies et al. [7], Lemma 3.1).
+
+    ``Enc(x)`` maps x to b-bit codes; ``Dec(y, Enc(x))`` recovers Q(x) with
+    any reference y with ``‖x − y‖`` small: a randomized Hadamard rotation,
+    then stochastically-rounded rotated coordinates mod 2^b, snapped by the
+    decoder to the representative nearest its own rotated reference. While
+    the wrap condition holds: E[Q(x)] = x, ‖Q(x) − x‖ ≤ γ·sqrt(d_pad), and
+    d·b + O(1) bits. γ comes from the encoder-local distance hint, so the
+    error is proportional to the model *distance*, never its norm (paper
+    §2.2). The math runs on the pipeline backend ``backend``.
 
     ``packed=False`` ships word-aligned uint codes (8/16/32 bits per
     coordinate — the historical wire format, and the honest accounting of
@@ -265,9 +328,6 @@ class LatticeCodec(CodecBase):
             raise ValueError(
                 f"lattice_packed needs bits in {{1, 2, 4, 8}} (whole codes "
                 f"per byte); got bits={self.bits}")
-        object.__setattr__(self, "quant", LatticeQuantizer(
-            bits=self.bits, block=self.block, safety=self.safety,
-            backend=self.backend))
 
     @property
     def pack(self) -> int:
@@ -280,7 +340,7 @@ class LatticeCodec(CodecBase):
 
     # -- per-message API (generic paths, mesh leaves, FedBuff deltas) ------
     def encode(self, key, x, hint):
-        msg = self.quant.encode(key, x, hint)
+        msg = _lattice_encode(self, key, x, hint)
         if self.pack > 1:
             codes = pack_codes(msg.codes[None].astype(jnp.uint32),
                                bits=self.bits, block=self.block)[0]
@@ -295,23 +355,23 @@ class LatticeCodec(CodecBase):
         if self.pack > 1:
             codes = unpack_codes(msg.codes[None], bits=self.bits,
                                  block=self.block)[0]
-            msg = LatticeMsg(codes=codes.astype(self.quant.code_dtype()),
-                             gamma=msg.gamma)
-        return self.quant.decode(key, msg, ref)
+            unpacked = wire_container_dtype(LatticeWire(self.bits))
+            msg = LatticeMsg(codes=codes.astype(unpacked), gamma=msg.gamma)
+        return _lattice_decode(self, key, msg, ref)
 
     def message_bits(self, d: int) -> int:
         per = self.bits if self.packed else _storage_bits(self.bits)
         return pad_len(d, self.block) * per + 32  # + γ scalar
 
     def code_dtype(self):
-        return jnp.uint8 if self.pack > 1 else self.quant.code_dtype()
+        return wire_container_dtype(self.wire())
 
     def wire_declaration(self, d: int) -> WireDecl:
         dp = pad_len(d, self.block)
         per = self.bits if self.packed else _storage_bits(self.bits)
         # packed wire: d_pad/pack uint8 containers each holding `pack`
         # codes; unpacked: d_pad containers at the storage width
-        container = 8 if self.packed else _storage_bits(self.bits)
+        container = jnp.dtype(self.code_dtype()).itemsize * 8
         return WireDecl(codec=self.name, parts=(
             WirePart("codes", dp // self.pack, container, dp * per,
                      "int", True),
@@ -352,9 +412,6 @@ class GroupedLatticeCodec(CodecBase):
         object.__setattr__(self, "bits", int(max(self.bits_per_client)))
         object.__setattr__(self, "_levels_j", jnp.asarray(
             [1 << int(b) for b in self.bits_per_client], jnp.float32))
-        object.__setattr__(self, "quant", LatticeQuantizer(
-            bits=self.bits, block=self.block, safety=self.safety,
-            backend=self.backend))
 
     @property
     def pack(self) -> int:
@@ -399,10 +456,10 @@ class GroupedLatticeCodec(CodecBase):
     # is not expressible with a shared jit cache — the grouped codec exists
     # for the batched pipeline path. Fall back to max-bits messages.
     def encode(self, key, x, hint):
-        return self.quant.encode(key, x, hint)
+        return _lattice_encode(self, key, x, hint)
 
     def decode(self, key, msg, ref):
-        return self.quant.decode(key, msg, ref)
+        return _lattice_decode(self, key, msg, ref)
 
 
 # ---------------------------------------------------------------------------
@@ -516,10 +573,6 @@ _CODECS: Dict[str, Any] = {
     "identity": _build_identity,
 }
 
-# FedConfig.quantizer legacy names -> codec registry names
-_LEGACY_QUANTIZER = {"lattice": "lattice", "qsgd": "scalar",
-                     "none": "identity"}
-
 
 def registered_codecs() -> Tuple[str, ...]:
     """Names accepted by :func:`make_codec`, in registration order."""
@@ -576,13 +629,12 @@ def make_codec(spec, *, bits: int = 8, backend: str = "jnp",
                          safety=safety, **params)
 
 
-def resolve_codec(spec, fed, *, direction: str, default: str = None,
+def resolve_codec(spec, fed, *, direction: str, default: str,
                   slow_mask=None) -> Codec:
     """Resolve an algorithm's per-direction codec.
 
     Precedence: explicit ``spec`` kwarg > ``fed.codec_up`` /
-    ``fed.codec_down`` > ``default`` > the legacy ``fed.quantizer`` map
-    (lattice | qsgd→scalar | none→identity). A dict spec
+    ``fed.codec_down`` > the algorithm's ``default``. A dict spec
     ``{"fast": ..., "slow": ...}`` (uplink only) resolves each group and
     combines lattice-family members into a :class:`GroupedLatticeCodec`
     over ``slow_mask`` (the boolean per-client straggler mask from the
@@ -590,12 +642,7 @@ def resolve_codec(spec, fed, *, direction: str, default: str = None,
     """
     backend = getattr(fed, "kernel_backend", "jnp")
     if spec is None:
-        spec = getattr(fed, f"codec_{direction}", "") or None
-    if spec is None:
-        spec = default or _LEGACY_QUANTIZER.get(fed.quantizer)
-        if spec is None:
-            raise ValueError(f"no codec mapping for quantizer "
-                             f"{fed.quantizer!r}")
+        spec = getattr(fed, f"codec_{direction}", "") or default
     if isinstance(spec, dict):
         if direction != "up":
             raise ValueError("per-client group codecs apply to the uplink "

@@ -2,7 +2,7 @@
 REJECTS (§2.2): transmitting quantized updates with a client-side error
 accumulator needs extra memory at the client and second-moment assumptions;
 the position-aware lattice quantizer needs neither. Implemented so the
-trade-off is runnable (see bench_quantizer tracking ablation and
+trade-off is runnable (see the tracking ablation in
 tests/test_error_feedback.py).
 """
 from __future__ import annotations
@@ -12,7 +12,7 @@ from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 
-from repro.compression.lattice import LatticeMsg, QSGDQuantizer
+from repro.compression.codecs import LatticeMsg, ScalarCodec
 
 
 class EFState(NamedTuple):
@@ -36,7 +36,7 @@ class ErrorFeedbackQSGD:
         ω = √d/levels can exceed 1), so the decoded value is scaled by the
         standard 1/(1+ω) to keep the EF recursion stable."""
         import numpy as np
-        q = QSGDQuantizer(bits=self.bits)
+        q = ScalarCodec(bits=self.bits)
         target = delta + state.error
         msg = q.encode(key, target)
         omega = np.sqrt(delta.shape[0]) / q.levels
@@ -44,4 +44,4 @@ class ErrorFeedbackQSGD:
         return msg, decoded, EFState(error=target - decoded)
 
     def message_bits(self, d: int) -> int:
-        return QSGDQuantizer(bits=self.bits).message_bits(d)
+        return ScalarCodec(bits=self.bits).message_bits(d)

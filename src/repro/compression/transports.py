@@ -52,6 +52,7 @@ import jax.numpy as jnp
 
 from repro.analysis.provenance import wire_mark
 from repro.kernels.exchange import block_geometry
+from repro.compression.pipeline import wire_container_dtype
 from repro.compression.rotation import dither, pad_len
 
 
@@ -327,11 +328,10 @@ class ReduceScatterSum:
         # gather moves the codes in their declared storage container (the
         # working uint32 of the unpacked path would quadruple the bytes);
         # snap consumes any uint container, as on the code_allgather path.
-        cont = (jnp.uint8 if wire.pack > 1 or wire.bits <= 8 else
-                (jnp.uint16 if wire.bits <= 16 else jnp.uint32))
         codes_all = _gather_flat(
-            wire_mark(codes_sh[0].astype(cont), channel="down",
-                      part="codes", codec="wire", d=d_sh), client_axis)
+            wire_mark(codes_sh[0].astype(wire_container_dtype(wire)),
+                      channel="down", part="codes", codec="wire", d=d_sh),
+            client_axis)
         gam_all = jax.lax.all_gather(
             wire_mark(gam_rs[0], channel="down", part="gamma",
                       codec="wire", d=d_sh), client_axis)   # (n,) f32

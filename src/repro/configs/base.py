@@ -205,13 +205,12 @@ class FedConfig:
     lr: float = 0.1                # eta (client SGD step)
     # paper App. A: 'Unless otherwise noted, we employ the unweighted version'
     weighted: bool = False         # eta_i = H_min / H_i dampening
-    quantizer: str = "lattice"     # 'lattice' | 'qsgd' | 'none'
     bits: int = 8
     # per-direction codec specs (repro.compression.codecs registry names,
-    # e.g. 'lattice_packed', 'scalar:bits=4', 'topk_ef:frac=0.01'); ""
-    # derives the historical scheme from `quantizer` + `bits` — every
-    # registry algorithm resolves its uplink/downlink compression from
-    # these unless given explicit uplink=/downlink= kwargs
+    # e.g. 'lattice_packed', 'scalar:bits=4', 'topk_ef:frac=0.01'); "" =
+    # the algorithm's own default at `bits` — every registry algorithm
+    # resolves its uplink/downlink compression from these unless given
+    # explicit uplink=/downlink= kwargs
     codec_up: str = ""
     codec_down: str = ""
     # compression-pipeline kernel backend (repro.compression.pipeline):

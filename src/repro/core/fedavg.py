@@ -72,8 +72,7 @@ class FedAvg:
     downlink: Any = None                # codec spec (default: identity)
     participation: Any = None           # spec (default: fed.participation)
     client_mesh: Any = None             # shard the store's client axis
-    # subclasses override the per-direction codec defaults (None = the
-    # legacy fed.quantizer map)
+    # subclasses override the per-direction codec defaults
     _codec_default_up = "identity"
     _codec_default_down = "identity"
 
@@ -232,16 +231,14 @@ class CompressedFedAvg(FedAvg):
     decoded against the ZERO vector (sound for position-aware and scalar
     codecs alike — exactly FedPAQ when ``uplink="scalar"``). Downlink: one
     broadcast Enc(X_t) decoded against the previous server model (every
-    client received that broadcast last round). Defaults: uplink from the
-    legacy ``fed.quantizer`` map (lattice at ``fed.bits``), downlink
-    ``identity``.
+    client received that broadcast last round). Defaults: uplink
+    ``lattice`` at ``fed.bits``, downlink ``identity``.
     """
     server_lr: float = 1.0
-    # uplink defaults to the legacy fed.quantizer map (None), downlink to
-    # the uncompressed broadcast; downlink stateful codecs degrade to
-    # their stateless encode (one broadcast encoder; only uplink
-    # residuals are threaded)
-    _codec_default_up = None
+    # uplink defaults to lattice, downlink to the uncompressed broadcast;
+    # downlink stateful codecs degrade to their stateless encode (one
+    # broadcast encoder; only uplink residuals are threaded)
+    _codec_default_up = "lattice"
     _codec_default_down = "identity"
 
     def _codec_state0(self):

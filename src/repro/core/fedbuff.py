@@ -3,23 +3,21 @@
 Clients run continuously; when client i finishes its K local steps (duration
 Gamma(K, λ_i)) it ships the model DELTA to a shared buffer and restarts from
 the current server model. Once the buffer holds Z updates the server applies
-the averaged delta. The deltas can be quantized (``quantize=True``) with:
+the averaged delta. ``uplink=`` / ``downlink=`` codec specs (or
+``FedConfig.codec_up`` / ``codec_down``) select ANY registered codec per
+direction; both default to ``identity`` (fp32). Two uplinks matter here:
 
-  * ``quantizer="qsgd"``    — the paper's Fig. 6/16 variant. FedBuff cannot
-    lattice-quantize *models* (the server has no decoding key for a
+  * ``uplink="scalar"``  — the paper's Fig. 6/16 QSGD variant. FedBuff
+    cannot lattice-quantize *models* (the server has no decoding key for a
     client's stale base model)…
-  * ``quantizer="lattice"`` — …but the DELTA is position-aware decodable
+  * ``uplink="lattice"`` — …but the DELTA is position-aware decodable
     against the zero vector with hint ‖Δ‖, so delta compression rides the
     same fused rotate+quantize pipeline as QuAFL (backend selected by
     ``FedConfig.kernel_backend``). Beyond-paper option.
 
-Both knobs are now views over the composable codec API: ``uplink=`` /
-``downlink=`` specs (or ``FedConfig.codec_up`` / ``codec_down``) select
-ANY registered codec per direction — the legacy quantize/quantizer pair
-maps onto the equivalent codec so seeded legacy runs are unchanged draw
-for draw, and a stateful uplink codec (``topk_ef``) gets its per-client
-error-feedback residuals threaded through ``FedBuffState.ef`` on this
-python implementation.
+A stateful uplink codec (``topk_ef``) gets its per-client error-feedback
+residuals threaded through ``FedBuffState.ef`` on this python
+implementation.
 
 FedBuff's control flow is data-dependent, so it is simulated (event-driven
 python around a jitted local-steps function) rather than SPMD. The event
@@ -48,7 +46,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.compression.codecs import IdentityCodec, resolve_codec
-from repro.compression.lattice import make_quantizer
 from repro.configs.base import FedConfig
 from repro.fed.clock import (ArrivalQueue, completion_time,
                              completion_time_device, speeds_for)
@@ -94,29 +91,18 @@ class FedBuff:
     batch_fn: Callable[[Any, jax.Array], Any]
     buffer_size: int = 10
     server_lr: float = 1.0
-    quantize: bool = False
-    quantizer: str = "qsgd"   # 'qsgd' (paper) | 'lattice' (delta-vs-zero)
     uniform_speeds: bool = False
-    uplink: Any = None        # codec spec; default derives from the legacy
-    #                         # quantize/quantizer knobs (identity when off)
+    uplink: Any = None        # codec spec for the deltas (default identity;
+    #                         # 'scalar' is the paper's, 'lattice' decodes
+    #                         # the delta against zero)
     downlink: Any = None      # codec spec for the restart broadcast
     #                         # (default identity: fp32 server model)
 
     def __post_init__(self):
         n = self.fed.n_clients
         self.lam = speeds_for(self.fed, n, uniform=self.uniform_speeds)
-        self.quant = make_quantizer(self.quantizer if self.quantize
-                                    else "none", self.fed.bits,
-                                    getattr(self.fed, "kernel_backend",
-                                            "jnp"))
-        # per-direction codecs; the legacy quantize/quantizer pair maps to
-        # the equivalent codec (qsgd -> scalar, lattice -> lattice) so
-        # seeded legacy runs are unchanged draw for draw
-        legacy_up = ({"qsgd": "scalar", "lattice": "lattice",
-                      "none": "identity"}.get(self.quantizer, "identity")
-                     if self.quantize else "identity")
         self.codec_up = resolve_codec(self.uplink, self.fed, direction="up",
-                                      default=legacy_up)
+                                      default="identity")
         self.codec_down = resolve_codec(self.downlink, self.fed,
                                         direction="down",
                                         default="identity")

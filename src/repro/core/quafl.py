@@ -31,9 +31,10 @@ Faithfulness notes:
    X^i ← Q(X_t)/(s+1) + s·Y^i/(s+1) — preserves the model mean μ_t up to
    gradient and quantization noise (the paper's potential argument).
 
-Perf: with ``quantizer="lattice"`` the whole exchange runs through the
-rotated-space compression pipeline (repro.compression.pipeline): one shared
-per-round rotation key, all encode/decode/averaging in rotated coordinates,
+Perf: with lattice-family codecs both ways (the default) the whole exchange
+runs through the rotated-space compression pipeline
+(repro.compression.pipeline): one shared per-round rotation key, all
+encode/decode/averaging in rotated coordinates,
 exactly s+1 forward + s+1 inverse full-model rotations per round (the seed
 composition spent ~5s+1; the downlink Enc(X_t) is an elementwise quantize of
 the cached rotated server). ``FedConfig.kernel_backend`` selects the
@@ -72,7 +73,6 @@ import numpy as np
 from repro.compression.codecs import (GroupedLatticeCodec,
                                       init_client_states, is_lattice_family,
                                       resolve_codec)
-from repro.compression.lattice import make_quantizer
 from repro.compression.pipeline import ExchangePipeline
 from repro.configs.base import FedConfig
 # canonical home is repro.fed.clock; re-exported here for compatibility
@@ -137,24 +137,22 @@ class QuAFL:
     avg_mode: str = "both"                 # 'both'|'server_only'|'client_only'
     uniform_speeds: bool = False
     exchange_impl: str = "pipeline"        # 'pipeline' | 'reference' (oracle)
-    uplink: Any = None                     # codec spec (default: fed-derived)
-    downlink: Any = None                   # codec spec (default: fed-derived)
+    uplink: Any = None                     # codec spec (default: lattice)
+    downlink: Any = None                   # codec spec (default: lattice)
     participation: Any = None              # spec (default: fed.participation)
     client_mesh: Any = None                # shard the store's client axis
 
     def __post_init__(self):
         backend = getattr(self.fed, "kernel_backend", "jnp")
-        self.quant = make_quantizer(self.fed.quantizer, self.fed.bits,
-                                    backend)
         n = self.fed.n_clients
         self.lam = speeds_for(self.fed, n, uniform=self.uniform_speeds)
         # per-direction codecs; the straggler mask resolves group specs
         # ({"fast": ..., "slow": ...}) into per-client bit budgets
         slow_mask = np.asarray(self.lam) == np.float32(self.fed.lam_slow)
         self.codec_up = resolve_codec(self.uplink, self.fed, direction="up",
-                                      slow_mask=slow_mask)
+                                      default="lattice", slow_mask=slow_mask)
         self.codec_down = resolve_codec(self.downlink, self.fed,
-                                        direction="down")
+                                        direction="down", default="lattice")
         # rotated-space exchange engine whenever BOTH directions are
         # lattice-family (QSGD/identity/top-k have no rotation to
         # restructure around); shares every knob with the codecs so bit

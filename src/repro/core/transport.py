@@ -6,7 +6,7 @@ rotation blocks never cross leaves). Algebraically this is still a valid
 instance of the blockwise lattice quantizer — the rotation is block-diagonal
 either way — and it keeps every encode/decode local to the shards that own
 the leaf. The helpers are CODEC-AGNOSTIC: any
-:mod:`repro.compression.codecs` object (or legacy quantizer) with
+:mod:`repro.compression.codecs` object with
 ``encode(key, x, hint) / decode(key, msg, ref) / message_bits(d)`` rides
 them, and messages are opaque pytrees.
 
@@ -32,7 +32,7 @@ from typing import Any, Dict
 
 import jax.numpy as jnp
 
-from repro.compression.lattice import LatticeMsg
+from repro.compression.codecs import LatticeMsg
 from repro.utils.tree import fold_in_str
 
 

@@ -12,8 +12,7 @@ harness) instead of hand-wiring a class per experiment:
   ``fedavg``          synchronous FedAvg (waits for stragglers,
                       uncompressed); kwargs: ``uniform_speeds``
   ``fedbuff``         buffered asynchronous aggregation; kwargs:
-                      ``buffer_size``, ``server_lr``, ``quantize``,
-                      ``quantizer``, ``uniform_speeds``
+                      ``buffer_size``, ``server_lr``, ``uniform_speeds``
   ``sequential``      single slow node, one step per round (paper Fig. 3)
   ``quafl_scaffold``  QuAFL + SCAFFOLD control variates (beyond-paper);
                       QuAFL kwargs
@@ -36,8 +35,10 @@ Every algorithm that communicates additionally accepts ``uplink=`` /
 ``downlink=`` codec specs (:mod:`repro.compression.codecs` — names like
 ``"lattice_packed"``, ``"scalar:bits=4"``, or a ``{"fast": ..., "slow":
 ...}`` per-client group map), defaulting to ``FedConfig.codec_up`` /
-``codec_down`` and then to the algorithm's historical scheme; the metrics'
-``bits_up`` / ``bits_down`` are computed by the selected codecs.
+``codec_down`` and then to the algorithm's own default (``lattice`` for
+the QuAFL family, ``spmd`` and ``compressed_fedavg``'s uplink, ``identity``
+elsewhere); the metrics' ``bits_up`` / ``bits_down`` are computed by the
+selected codecs.
 
 The round-sampling algorithms (``quafl``, ``fedavg``, ``quafl_scaffold``,
 ``adaptive_quafl``, ``compressed_fedavg``) also accept ``participation=``

@@ -7,7 +7,7 @@ stragglers; FedBuff flushes a buffer). :func:`simulate` runs one
 :class:`repro.fed.FedAlgorithm` until its budget is exhausted and emits ONE
 trace format; :func:`compare` does it for a named set of algorithms under
 identical seeds and budgets, which is the apples-to-apples harness every
-figure-style experiment (and ``benchmarks/bench_algorithms.py``) drives.
+figure-style experiment drives.
 
 A trace row is a plain dict with the standardized metrics schema keys
 (:data:`repro.fed.api.METRIC_KEYS`, all PER-ROUND exactly as the algorithm

@@ -1,11 +1,8 @@
 # Pallas kernels for the paper's compute hot-spots, all swept against the
 # pure-jnp oracles in ref.py (tests/test_kernels.py):
-#   hadamard.py      — blocked H_r (x) H_c rotation core (MXU matmuls)
-#   lattice_quant.py — elementwise encode/decode streams
 #   exchange.py      — fused rotated-space exchange (rotate+round+wrap /
 #                      snap+inverse-rotate), batched over messages; the
 #                      production path via repro.compression.pipeline
 #   flash_attention.py — attention tile for the model substrate
-#   ops.py           — public jit'd wrappers (interpret chosen by caller)
 #   geometry.py      — Hadamard block geometry + fp32 matmul, shared with
 #                      repro.compression.rotation (imports nothing of repro)

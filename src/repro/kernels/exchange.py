@@ -23,7 +23,7 @@ pass per (r, c) Hadamard block:
                         once at the end of the round)
   * ``fused_decode``  — rotate the reference + snap + inverse rotate: the
                         full ``Dec(ref, msg)`` in one pass (used by the
-                        leaf-wise transport and the quantizer API)
+                        leaf-wise transport and the per-message codec API)
 
 **Sub-byte packing** (the ``lattice_packed`` codec): for ``bits`` in
 {1, 2, 4} the encode-side kernels accept ``pack = 8 // bits`` and emit

@@ -55,8 +55,7 @@ def main():
         from repro.fed import make_algorithm, simulate
         from repro.models.model import lm_loss
 
-        fed = FedConfig(n_clients=4, s=4, local_steps=2, lr=0.05,
-                        quantizer="lattice")
+        fed = FedConfig(n_clients=4, s=4, local_steps=2, lr=0.05)
         pool, batch, seq = 8, 2, 32
         data, batch_fn = federated_token_task(args.seed, fed.n_clients,
                                               pool, batch, seq,

@@ -90,9 +90,10 @@ class SpmdAlgorithm:
                             "train")
         # per-direction codecs drive both the step build and the metrics'
         # wire accounting (bits computed BY the codec, per leaf)
-        self.codec_up = resolve_codec(None, self.fed, direction="up")
-        self.codec_down = resolve_codec(None, self.fed, direction="down")
-        self.quant = self.codec_up   # legacy accessor
+        self.codec_up = resolve_codec(None, self.fed, direction="up",
+                                      default="lattice")
+        self.codec_down = resolve_codec(None, self.fed, direction="down",
+                                        default="lattice")
         quantized = not (isinstance(self.codec_up, IdentityCodec)
                          and isinstance(self.codec_down, IdentityCodec))
         with self.mesh:

@@ -107,15 +107,13 @@ def build_train_step(cfg: ModelConfig, fed: FedConfig, mesh, shape: ShapeConfig,
     n_slots = n_slots_for(mesh, fed_mode)
     rules = rules_for_mode(fed_mode)
     K, lr = fed.local_steps, fed.lr
-    # per-direction codecs (repro.compression.codecs): the legacy
-    # fed.quantizer map by default, any registry codec via fed.codec_up /
-    # codec_down; `quantized=False` forces the uncompressed identity pair
-    if quantized:
-        quant_up = resolve_codec(None, fed, direction="up")
-        quant_down = resolve_codec(None, fed, direction="down")
-    else:
-        quant_up = resolve_codec("identity", fed, direction="up")
-        quant_down = resolve_codec("identity", fed, direction="down")
+    # per-direction codecs (repro.compression.codecs): lattice by default,
+    # any registry codec via fed.codec_up / codec_down; `quantized=False`
+    # forces the uncompressed identity pair
+    spec = None if quantized else "identity"
+    quant_up = resolve_codec(spec, fed, direction="up", default="lattice")
+    quant_down = resolve_codec(spec, fed, direction="down",
+                               default="lattice")
     # stateful codecs degrade to their stateless encode on the mesh path
     # (no per-client residual buffers in the train state)
 

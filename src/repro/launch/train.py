@@ -151,13 +151,12 @@ def main():
     ap.add_argument("--local-steps", type=int, default=2)
     ap.add_argument("--lr", type=float, default=0.02)
     ap.add_argument("--bits", type=int, default=8)
-    ap.add_argument("--quantizer", default="lattice")
     ap.add_argument("--codec-up", default="",
                     help="uplink codec spec (repro.compression.codecs "
                          "registry: lattice|lattice_packed|topk_ef|scalar|"
                          "identity, with name:key=val params, e.g. "
-                         "'lattice_packed:bits=4'); empty derives from "
-                         "--quantizer/--bits")
+                         "'lattice_packed:bits=4'); empty = the "
+                         "algorithm's default at --bits")
     ap.add_argument("--codec-down", default="",
                     help="downlink codec spec (same registry / syntax as "
                          "--codec-up)")
@@ -194,7 +193,7 @@ def main():
                          f"round than the population holds")
     fed = FedConfig(n_clients=n_clients, s=args.n_slots,
                     local_steps=args.local_steps, lr=args.lr,
-                    bits=args.bits, quantizer=args.quantizer,
+                    bits=args.bits,
                     codec_up=args.codec_up, codec_down=args.codec_down,
                     transport=args.transport,
                     participation=args.participation,

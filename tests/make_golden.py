@@ -37,8 +37,7 @@ GOLDEN = {
     "quafl": dict(),
     "quafl_scaffold": dict(),
     "fedavg": dict(),
-    "fedbuff_device": dict(buffer_size=2, quantize=True,
-                           quantizer="lattice"),
+    "fedbuff_device": dict(buffer_size=2, uplink="lattice"),
 }
 
 

@@ -248,8 +248,8 @@ def test_rs_transport_audit_clean_and_byte_gate_trips():
     fed = FedConfig(n_clients=n, s=n, bits=8,
                     codec_up="lattice_packed:bits=4",
                     codec_down="lattice_packed:bits=4")
-    up = resolve_codec(None, fed, direction="up")
-    dn = resolve_codec(None, fed, direction="down")
+    up = resolve_codec(None, fed, direction="up", default="lattice")
+    dn = resolve_codec(None, fed, direction="down", default="lattice")
     ex = make_shardlocal_exchange(
         up, dn, mesh, {"w": P()}, {"w": P("data")}, "data", n,
         transport=transport_for_mode("shard_local"))

@@ -21,13 +21,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.analysis.opbudget import (EXCHANGE_BLOCKS, EXCHANGE_GRID_STEPS,
                                      exchange_grid_counts)
-from repro.compression.lattice import LatticeQuantizer
+from repro.compression.codecs import LatticeCodec
 from repro.compression.rotation import _signs, dither
 from repro.kernels.exchange import (fused_decode, fused_encode, fused_rotate,
                                     quantize_codes, snap_codes)
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.hadamard import hadamard_blocks
-from repro.kernels.lattice_quant import lattice_decode, lattice_encode
 from repro.utils.spans import NOISE
 
 D = 1 << 20             # 64 Hadamard blocks of 128 x 128
@@ -267,7 +265,7 @@ def test_vmapped_leaf_exchange_takes_groups_of_blocks():
     """The round's leaf exchange, traced at an OLMo MLP leaf (abstract
     shapes): each slot's encode and decode step over 8 or more blocks."""
     d = OLMO_NB * 128 * 128
-    q = LatticeQuantizer(backend="pallas")
+    q = LatticeCodec(backend="pallas")
     keys = jax.ShapeDtypeStruct((1, 2), jnp.uint32)
     x = jax.ShapeDtypeStruct((1, d), jnp.float32)
     hint = jax.ShapeDtypeStruct((1,), jnp.float32)
@@ -298,7 +296,7 @@ def test_vmapped_noise_draws_are_sublane_dense(one_chip, what):
         text = _compile(jax.vmap(lambda k: (dither(k, (D,)), _signs(k, D))),
                         key)
     else:
-        q = LatticeQuantizer(backend="pallas")
+        q = LatticeCodec(backend="pallas")
         text = _compile(jax.vmap(q.encode), key, _f32(one_chip, 1, D),
                         _f32(one_chip, 1))
         assert "tpu_custom_call" in text
@@ -316,20 +314,6 @@ def test_flash_attention_compiles_at_llama_heads(one_chip):
                                                     interpret=False),
                     q, kv, kv)
     assert "tpu_custom_call" in text
-
-
-def test_hadamard_and_lattice_quant_compile(one_chip):
-    text = _compile(lambda x: hadamard_blocks(x, interpret=False),
-                    _f32(one_chip, 64, 128, 128))
-    assert "tpu_custom_call" in text
-
-    def enc_dec(y, u, w):
-        codes = lattice_encode(y, u, 0.02, bits=8, interpret=False)
-        return lattice_decode(codes, w, 0.02, bits=8, interpret=False)
-
-    text = _compile(enc_dec, _f32(one_chip, D), _f32(one_chip, D),
-                    _f32(one_chip, D))
-    assert text.count("tpu_custom_call") >= 2
 
 
 def test_round_kernels_sit_in_the_exchange_scope(one_chip):

@@ -187,8 +187,7 @@ GOLDEN_KW = {
     "quafl": {},
     "quafl_scaffold": {},
     "fedavg": {},
-    "fedbuff_device": dict(buffer_size=2, quantize=True,
-                           quantizer="lattice"),
+    "fedbuff_device": dict(buffer_size=2, uplink="lattice"),
 }
 
 
@@ -405,9 +404,11 @@ def test_grouped_codec_charges_per_client_bits():
     assert np.isfinite(float(m["quant_err"]))
     # group codecs are uplink-only and need the speed classes
     with pytest.raises(ValueError):
-        resolve_codec({"fast": "lattice"}, fed, direction="down")
+        resolve_codec({"fast": "lattice"}, fed, direction="down",
+                      default="lattice")
     with pytest.raises(ValueError):
-        resolve_codec({"fast": "lattice"}, fed, direction="up")
+        resolve_codec({"fast": "lattice"}, fed, direction="up",
+                      default="lattice")
 
 
 def test_grouped_codec_codes_bit_exact_across_backends():
@@ -477,7 +478,7 @@ def test_grouped_unpacked_members_charge_storage_width():
     bits/coord) — only packed members earn the sub-byte accounting."""
     fed = FedConfig(n_clients=4, s=2, bits=8)
     g = resolve_codec({"fast": "lattice", "slow": "lattice:bits=4"}, fed,
-                      direction="up",
+                      direction="up", default="lattice",
                       slow_mask=np.array([1, 1, 0, 0], bool))
     assert g.bits_per_client == (4, 4, 8, 8)
     assert g.wire_width_per_client == (8, 8, 8, 8)
@@ -518,7 +519,7 @@ def test_transport_extra_bits_down():
     # a grouped uplink gathers a second (levels) row per client
     fed = FedConfig(n_clients=4, s=2, bits=8)
     g = resolve_codec({"fast": "lattice", "slow": "lattice:bits=4"}, fed,
-                      direction="up",
+                      direction="up", default="lattice",
                       slow_mask=np.array([1, 1, 0, 0], bool))
     assert ag.extra_bits_down(g, dn, d, n) == 2 * (n - 1) * 32
     # fused reduce_scatter: one downlink-width coded message + n-1 γ shards

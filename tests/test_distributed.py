@@ -86,7 +86,7 @@ def test_train_step_mean_preservation_quantfree():
     """lr=0 + no quantization: server+clients mean is exactly preserved
     by the distributed step too."""
     cfg = get_reduced("olmo-1b")
-    fed = FedConfig(local_steps=1, lr=0.0, quantizer="none")
+    fed = FedConfig(local_steps=1, lr=0.0)
     shape = ShapeConfig("tiny", 16, 4, "train")
     mesh = _mesh11()
     with mesh:

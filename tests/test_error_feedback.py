@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compression import LatticeQuantizer, QSGDQuantizer
+from repro.compression import LatticeCodec, ScalarCodec
 from repro.compression.error_feedback import ErrorFeedbackQSGD
 
 
@@ -27,8 +27,8 @@ def _drift(key, d, steps, scale=0.05):
 def run_tracking(d=4096, steps=30, bits=6, seed=0):
     key = jax.random.PRNGKey(seed)
     xs = _drift(key, d, steps)
-    lat = LatticeQuantizer(bits=bits)
-    qsg = QSGDQuantizer(bits=bits)
+    lat = LatticeCodec(bits=bits)
+    qsg = ScalarCodec(bits=bits)
     ef = ErrorFeedbackQSGD(bits=bits)
 
     est_lat = xs[0]

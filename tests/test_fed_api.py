@@ -21,7 +21,7 @@ from repro.configs.base import FedConfig
 from repro.core import (AdaptiveBits, AdaptiveQuAFL, FedAvg, FedBuff, QuAFL,
                         QuaflScaffold, Sequential)
 from repro.core.transport import tree_bits
-from repro.compression.lattice import make_quantizer
+from repro.compression import make_codec
 from repro.data import make_federated_classification
 from repro.data.synthetic import client_batch
 from repro.fed import (FedAlgorithm, METRIC_KEYS, compare, make_algorithm,
@@ -58,7 +58,7 @@ def _smoke_setup():
     """Shared tiny task for the perf_smoke tests (built once per session)."""
     if not _SMOKE_CACHE:
         fed = FedConfig(n_clients=2, s=1, local_steps=1, lr=0.2, bits=6,
-                        quantizer="qsgd")
+                        codec_up="scalar", codec_down="scalar")
         _SMOKE_CACHE["v"] = (fed,) + _setup(fed, d=8, hidden=8, classes=2)
     return _SMOKE_CACHE["v"]
 
@@ -84,8 +84,9 @@ def test_registry_names_and_protocol():
 def test_every_registered_algorithm_steps_once():
     """Instantiate and step EVERY registry algorithm once (CI smoke).
 
-    Deliberately minimal shapes (1 sampled client, 1 local step, qsgd — the
-    lattice pipeline would pad to the 16k Hadamard block): the budget is six
+    Deliberately minimal shapes (1 sampled client, 1 local step, the
+    scalar codec both ways — the lattice pipeline would pad to the 16k
+    Hadamard block): the budget is six
     XLA compiles in <10s, and this test only checks the registry ->
     protocol -> metrics-schema plumbing. The jitted lattice paths are
     pinned by the non-smoke tests here and by test_pipeline.py."""
@@ -189,7 +190,7 @@ def test_quafl_bits_split_matches_tree_bits():
     st0 = alg.init(params0)
     st1, m = alg.round(st0, part, jax.random.PRNGKey(0))
     msg_tree = {"model": jnp.zeros((alg.d,))}   # one flat model message
-    per_msg = tree_bits(alg.quant, msg_tree)
+    per_msg = tree_bits(alg.codec_up, msg_tree)
     # s uplink messages, ONE downlink broadcast
     assert float(st1.bits_up) == fed.s * per_msg == float(m["bits_up"])
     assert float(st1.bits_down) == per_msg == float(m["bits_down"])
@@ -202,7 +203,7 @@ def test_fedavg_bits_split_matches_tree_bits():
     alg = make_algorithm("fedavg", fed, loss_fn=mlp_loss, template=params0,
                          batch_fn=bf)
     st1, m = alg.round(alg.init(params0), part, jax.random.PRNGKey(0))
-    per_msg = tree_bits(make_quantizer("none", 32), {"m": jnp.zeros((alg.d,))})
+    per_msg = tree_bits(make_codec("identity"), {"m": jnp.zeros((alg.d,))})
     # uncompressed model each way for each of the s sampled clients
     assert float(st1.bits_up) == fed.s * per_msg == float(m["bits_up"])
     assert float(st1.bits_down) == fed.s * per_msg == float(m["bits_down"])
@@ -214,7 +215,7 @@ def test_scaffold_bits_split_is_doubled_quafl():
     alg = make_algorithm("quafl_scaffold", fed, loss_fn=mlp_loss,
                          template=params0, batch_fn=bf)
     st1, m = alg.round(alg.init(params0), part, jax.random.PRNGKey(0))
-    per_msg = tree_bits(alg.quant, {"m": jnp.zeros((alg.d,))})
+    per_msg = tree_bits(alg.codec_up, {"m": jnp.zeros((alg.d,))})
     # model + control variate ride the exchange in both directions
     assert float(st1.base.bits_up) == 2 * fed.s * per_msg
     assert float(st1.base.bits_down) == 2 * per_msg
@@ -225,10 +226,9 @@ def test_fedbuff_bits_split_per_flush():
     fed = FedConfig(n_clients=4, s=2, local_steps=1, bits=8)
     part, test, params0, bf = _setup(fed)
     alg = make_algorithm("fedbuff", fed, loss_fn=mlp_loss, template=params0,
-                         batch_fn=bf, buffer_size=3, quantize=True,
-                         quantizer="lattice")
+                         batch_fn=bf, buffer_size=3, uplink="lattice")
     st1, m = alg.round(alg.init(params0), part, jax.random.PRNGKey(2))
-    per_up = tree_bits(alg.quant, {"m": jnp.zeros((alg.d,))})
+    per_up = tree_bits(alg.codec_up, {"m": jnp.zeros((alg.d,))})
     # one quantized delta up + one fp32 restart model down per completion
     assert float(m["bits_up"]) == 3 * per_up
     assert float(m["bits_down"]) == 3 * alg.d * 32
@@ -262,7 +262,7 @@ def test_compare_equal_bits_budget():
     part, test, params0, bf = _setup(fed)
     algs = {n: make_algorithm(n, fed, loss_fn=mlp_loss, template=params0,
                               batch_fn=bf) for n in ("quafl", "fedavg")}
-    budget = 40 * 4 * make_quantizer("lattice", 8).message_bits(
+    budget = 40 * 4 * make_codec("lattice", bits=8).message_bits(
         algs["quafl"].d)
     traces = compare(algs, params0, part, jax.random.PRNGKey(2),
                      until_bits=budget, eval_every=0)

@@ -50,7 +50,7 @@ def test_fedbuff_quantized():
     fed = FedConfig(n_clients=6, s=3, local_steps=2, lr=0.2, bits=8)
     part, test, params0, bf = _setup(fed)
     alg = FedBuff(fed=fed, loss_fn=mlp_loss, template=params0, batch_fn=bf,
-                  buffer_size=3, quantize=True)
+                  buffer_size=3, uplink="scalar")
     hist = alg.run(params0, part, jax.random.PRNGKey(3), total_time=300.0,
                    eval_every=100.0,
                    eval_fn=lambda p: float(mlp_loss(p, test)[0]))

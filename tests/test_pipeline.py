@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compression import ExchangePipeline, get_backend, make_quantizer
+from repro.compression import ExchangePipeline, get_backend, make_codec
 from repro.compression.rotation import pad_len
 from repro.configs.base import FedConfig
 from repro.core import QuAFL
@@ -108,7 +108,7 @@ def test_backend_registry_names():
     with pytest.raises(ValueError):
         get_backend("cuda")
     with pytest.raises(ValueError):
-        make_quantizer("lattice", 8, backend="bogus").encode(
+        make_codec("lattice", backend="bogus").encode(
             jax.random.PRNGKey(0), jnp.ones(8), 1.0)
 
 
@@ -116,7 +116,7 @@ def test_backend_registry_names():
 @pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
 def test_backend_quantizer_roundtrip(backend):
     d = 3000
-    q = make_quantizer("lattice", 8, backend=backend)
+    q = make_codec("lattice", bits=8, backend=backend)
     key = jax.random.PRNGKey(2)
     x = jax.random.normal(key, (d,))
     ref = x + 0.02 * jax.random.normal(jax.random.fold_in(key, 1), (d,))
@@ -135,7 +135,7 @@ def test_backends_agree_on_codes_and_decode():
     hint = jnp.linalg.norm(x - ref)
     msgs, outs = {}, {}
     for backend in ("jnp", "pallas_interpret"):
-        q = make_quantizer("lattice", 8, backend=backend)
+        q = make_codec("lattice", bits=8, backend=backend)
         msgs[backend] = q.encode(key, x, hint)
         outs[backend] = q.decode(key, msgs[backend], ref)
     a, b = msgs["jnp"], msgs["pallas_interpret"]
@@ -155,4 +155,5 @@ def test_fedconfig_backend_reaches_pipeline():
                     kernel_backend="pallas_interpret")
     alg, _, _ = _setup(fed)
     assert alg.pipeline.backend == "pallas_interpret"
-    assert alg.quant.backend == "pallas_interpret"
+    assert alg.codec_up.backend == "pallas_interpret"
+    assert alg.codec_down.backend == "pallas_interpret"

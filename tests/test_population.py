@@ -255,8 +255,7 @@ GOLDEN_ALGS = {
     "quafl": {},
     "quafl_scaffold": {},
     "fedavg": {},
-    "fedbuff_device": dict(buffer_size=2, quantize=True,
-                           quantizer="lattice"),
+    "fedbuff_device": dict(buffer_size=2, uplink="lattice"),
 }
 
 
@@ -292,13 +291,15 @@ def test_population_path_matches_pr3_golden(name):
 def test_population_larger_than_cohort_trains():
     """n_clients > s through every sampling algorithm: the store holds n
     rows, the round exchanges s messages (bits accounting unchanged)."""
-    fed = FedConfig(n_clients=24, s=4, local_steps=2, lr=0.3, bits=8,
-                    quantizer="qsgd")
+    fed = FedConfig(n_clients=24, s=4, local_steps=2, lr=0.3, bits=8)
     part, test, params0, bf = _setup(fed)
     for name in ("quafl", "fedavg", "quafl_scaffold"):
+        kw = ({"uplink": "scalar", "downlink": "scalar"}
+              if name.startswith("quafl") else {})
         alg = make_algorithm(name, fed, loss_fn=mlp_loss, template=params0,
                              batch_fn=bf,
-                             participation="gamma_straggler:strength=1")
+                             participation="gamma_straggler:strength=1",
+                             **kw)
         tr = simulate(alg, params0, part, jax.random.PRNGKey(2), rounds=4,
                       eval_every=0)
         v = np.asarray(tree_flatten_vector(alg.eval_params(tr.final_state)))
@@ -317,11 +318,12 @@ def test_cyclic_schedule_deterministic_across_chunk_boundaries(name):
     must be bit-for-bit the eager run, because the schedule is a pure
     function of the round counter t carried in the state."""
     fed = FedConfig(n_clients=8, s=2, local_steps=2, lr=0.3,
-                    quantizer="qsgd",
                     participation="cyclic:period=4,phase_groups=2")
     part, test, params0, bf = _setup(fed)
+    kw = ({"uplink": "scalar", "downlink": "scalar"} if name == "quafl"
+          else {})
     alg = make_algorithm(name, fed, loss_fn=mlp_loss, template=params0,
-                         batch_fn=bf)
+                         batch_fn=bf, **kw)
     run = lambda chunk: simulate(alg, params0, part, jax.random.PRNGKey(5),
                                  rounds=8, eval_every=0, record_every=1,
                                  scan_chunk=chunk)
@@ -340,7 +342,7 @@ def test_cyclic_last_time_rows_respect_schedule():
     phase (2 rounds of group 0) no group-1 client may have a last_time
     update yet, and over a full period every group gets touched."""
     fed = FedConfig(n_clients=8, s=4, local_steps=1, lr=0.1,
-                    quantizer="qsgd",
+                    codec_up="scalar", codec_down="scalar",
                     participation="cyclic:period=2,phase_groups=2")
     part, test, params0, bf = _setup(fed)
     alg = make_algorithm("quafl", fed, loss_fn=mlp_loss, template=params0,
@@ -374,7 +376,8 @@ from repro.utils.tree import tree_flatten_vector
 
 assert jax.device_count() == 8
 mesh = client_mesh()
-fed = FedConfig(n_clients=16, s=4, local_steps=2, lr=0.3, quantizer="qsgd",
+fed = FedConfig(n_clients=16, s=4, local_steps=2, lr=0.3,
+                codec_up="scalar", codec_down="scalar",
                 participation="gamma_straggler:strength=1")
 
 # 1) sharding moves placement, never values
@@ -434,7 +437,7 @@ def test_rng_and_rounds_stable_under_resharding_8dev():
 
 def test_auto_chunk_matches_explicit_bitwise():
     fed = FedConfig(n_clients=8, s=3, local_steps=2, lr=0.3,
-                    quantizer="qsgd")
+                    codec_up="scalar", codec_down="scalar")
     part, test, params0, bf = _setup(fed)
     alg = make_algorithm("quafl", fed, loss_fn=mlp_loss, template=params0,
                          batch_fn=bf)
@@ -455,8 +458,7 @@ def test_auto_chunk_matches_explicit_bitwise():
 def test_auto_chunk_capped_by_eval_cadence():
     """Autotune must never pick a chunk longer than the eval cadence —
     evals only fire on chunk boundaries."""
-    fed = FedConfig(n_clients=6, s=2, local_steps=1, lr=0.2,
-                    quantizer="qsgd")
+    fed = FedConfig(n_clients=6, s=2, local_steps=1, lr=0.2)
     part, test, params0, bf = _setup(fed)
     alg = make_algorithm("fedavg", fed, loss_fn=mlp_loss, template=params0,
                          batch_fn=bf)
@@ -469,8 +471,7 @@ def test_auto_chunk_capped_by_eval_cadence():
 
 
 def test_auto_chunk_falls_back_eager_for_host_algorithms():
-    fed = FedConfig(n_clients=4, s=2, local_steps=1, lr=0.2,
-                    quantizer="qsgd")
+    fed = FedConfig(n_clients=4, s=2, local_steps=1, lr=0.2)
     part, test, params0, bf = _setup(fed)
     alg = make_algorithm("fedbuff", fed, loss_fn=mlp_loss, template=params0,
                          batch_fn=bf, buffer_size=2)
@@ -485,7 +486,7 @@ def test_auto_chunk_falls_back_eager_for_host_algorithms():
 
 def _flat_alg(n_clients: int, d: int = 256):
     fed = FedConfig(n_clients=n_clients, s=8, local_steps=2, lr=0.01,
-                    quantizer="none")
+                    codec_up="identity", codec_down="identity")
     key = jax.random.PRNGKey(0)
     params0 = {"w": 0.01 * jax.random.normal(key, (d,), jnp.float32)}
     data = {"c": jnp.ones((1, 4), jnp.float32)}   # shared tiny batch pool

@@ -24,7 +24,8 @@ def _setup(fed, seed=0, iid=True, **kw):
 def test_mean_preservation_no_steps_no_quant():
     """With lr=0 and no quantization, a round is pure (s+1)-averaging and
     the model mean μ_t is EXACTLY preserved (paper §2.2 'Model Averaging')."""
-    fed = FedConfig(n_clients=8, s=3, local_steps=2, lr=0.0, quantizer="none")
+    fed = FedConfig(n_clients=8, s=3, local_steps=2, lr=0.0,
+                    codec_up="identity", codec_down="identity")
     alg, st, part, _, key = _setup(fed)
     # diverge the clients artificially
     st = st.with_clients(st.clients + jax.random.normal(
@@ -37,7 +38,8 @@ def test_mean_preservation_no_steps_no_quant():
 
 def test_clients_contract_towards_server():
     """The (s+1)-averaging strictly decreases the potential Φ when lr=0."""
-    fed = FedConfig(n_clients=6, s=6, local_steps=1, lr=0.0, quantizer="none")
+    fed = FedConfig(n_clients=6, s=6, local_steps=1, lr=0.0,
+                    codec_up="identity", codec_down="identity")
     alg, st, part, _, key = _setup(fed)
     st = st.with_clients(st.clients + jax.random.normal(
         key, st.clients.shape))
@@ -52,10 +54,10 @@ def test_clients_contract_towards_server():
     assert phi(st2) < p0
 
 
-@pytest.mark.parametrize("quantizer", ["lattice", "qsgd", "none"])
-def test_quafl_converges(quantizer):
+@pytest.mark.parametrize("codec", ["lattice", "scalar", "identity"])
+def test_quafl_converges(codec):
     fed = FedConfig(n_clients=8, s=4, local_steps=4, lr=0.3,
-                    quantizer=quantizer, bits=10, swt=10.0)
+                    codec_up=codec, codec_down=codec, bits=10, swt=10.0)
     alg, st, part, test, key = _setup(fed)
     loss0, _ = mlp_loss(alg.eval_params(st), test)
     for _ in range(60):
@@ -115,7 +117,7 @@ def test_bits_accounting_monotone():
     st2, _ = alg.round(st1, part, key)
     assert float(st2.bits_sent) == 2 * float(st1.bits_sent) > 0
     # lattice: (s+1) messages of d_pad*b (+ γ) bits per round
-    assert float(m["bits"]) == (fed.s + 1) * alg.quant.message_bits(alg.d)
+    assert float(m["bits"]) == (fed.s + 1) * alg.codec_up.message_bits(alg.d)
 
 
 @pytest.mark.parametrize("mode", ["both", "server_only", "client_only"])

@@ -1,12 +1,12 @@
-"""Quantizer + rotation properties (paper Lemma 3.1)."""
+"""Lattice and scalar codec + rotation properties (paper Lemma 3.1)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.compression import (LatticeQuantizer, QSGDQuantizer, rotate,
-                               make_quantizer, pad_len)
+from repro.compression import (LatticeCodec, ScalarCodec, make_codec,
+                               pad_len, rotate, wrap_gamma)
 
 
 # --------------------------------------------------------------------------
@@ -43,7 +43,7 @@ def test_rotation_inverse(d, seed):
 def test_lattice_error_proportional_to_distance(bits, dist, seed):
     """Property 2: ‖Q(x) − x‖ ≤ C(b)·‖x − y‖, independent of ‖x‖."""
     d = 4097
-    q = LatticeQuantizer(bits=bits)
+    q = LatticeCodec(bits=bits)
     key = jax.random.PRNGKey(seed)
     ref = jax.random.normal(key, (d,)) * 100.0  # large-norm reference
     delta = jax.random.normal(jax.random.fold_in(key, 1), (d,))
@@ -63,7 +63,7 @@ def test_lattice_error_proportional_to_distance(bits, dist, seed):
 def test_lattice_unbiased():
     """Property 1: E[Dec(y, Enc(x))] = x (stochastic rounding)."""
     d, N = 2000, 300
-    q = LatticeQuantizer(bits=6)
+    q = LatticeCodec(bits=6)
     key = jax.random.PRNGKey(0)
     ref = jax.random.normal(key, (d,)) * 5
     x = ref + 0.1 * jax.random.normal(jax.random.fold_in(key, 1), (d,))
@@ -75,13 +75,14 @@ def test_lattice_unbiased():
 
     mean = jax.lax.map(one, jnp.arange(N)).mean(0)
     bias = float(jnp.linalg.norm(mean - x))
-    per_coord = float(q.gamma_for(dist, d))
+    per_coord = float(wrap_gamma(dist, d, bits=q.bits, block=q.block,
+                                 safety=q.safety))
     # bias ≈ γ·sqrt(d/12N) for unbiased SR; allow 5 sigma
     assert bias <= 5 * per_coord * np.sqrt(d / (12 * N)), bias
 
 
 def test_lattice_bits_accounting():
-    q = LatticeQuantizer(bits=8)
+    q = LatticeCodec(bits=8)
     assert q.message_bits(16384) == 16384 * 8 + 32
     assert q.message_bits(16385) == 2 * 16384 * 8 + 32  # padded
 
@@ -90,7 +91,7 @@ def test_lattice_bits_accounting():
 @given(bits=st.integers(4, 10), seed=st.integers(0, 100))
 def test_qsgd_unbiased_small(bits, seed):
     d, N = 256, 400
-    q = QSGDQuantizer(bits=bits)
+    q = ScalarCodec(bits=bits)
     key = jax.random.PRNGKey(seed)
     x = jax.random.normal(key, (d,))
 
@@ -103,11 +104,12 @@ def test_qsgd_unbiased_small(bits, seed):
     assert err < 0.2, err
 
 
-def test_make_quantizer_registry():
-    for name in ("lattice", "qsgd", "none"):
-        make_quantizer(name, 8)
+def test_make_codec_registry():
+    for name in ("lattice", "lattice_packed", "topk_ef", "scalar",
+                 "identity"):
+        assert make_codec(name, bits=8).name == name
     with pytest.raises(ValueError):
-        make_quantizer("bogus", 8)
+        make_codec("bogus", bits=8)
 
 
 def test_wrap_failure_mode():
@@ -115,7 +117,7 @@ def test_wrap_failure_mode():
     positional decode is wrong — the regime Lemma 3.4's potential bound
     exists to prevent."""
     d = 1024
-    q = LatticeQuantizer(bits=4, safety=1.0)
+    q = LatticeCodec(bits=4, safety=1.0)
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (d,))
     msg = q.encode(key, x, jnp.float32(0.01))  # hint far too small
